@@ -20,7 +20,7 @@ interpret mode measures Python, not hardware) across the serving matrix:
                              sharded path, not real interconnects)
   packed × chained/fused   — the ISSUE-7 comparison: chained per-kernel
                              decode vs the single-launch fused step, each
-                             with the HBM-roofline bound (B·HBM_BW /
+                             with the v5e HBM-roofline bound (B·BW /
                              per-step packed bytes) and its roofline_gap
   fused step vs scan       — kernel-level: T separate fused-step launches
                              vs one in-kernel scan launch at T ∈ {1, 8, 32}
@@ -127,7 +127,8 @@ def main():
     # more iters than the rows above keep per-launch overhead above the
     # wall-clock noise of a shared CPU host.
     from repro import hw
-    bound = B * hw.HBM_BW / pack_report["packed_bytes"]
+    bw = hw.peaks(hw.TARGET_KIND).hbm_bytes_per_s    # the v5e bound
+    bound = B * bw / pack_report["packed_bytes"]
     G2 = 4 * G
     toks2 = B * G2
     ceng = ServeEngine(model.with_fused(False), cfg, max_len=P + G2,
@@ -172,7 +173,6 @@ def main():
 # from HBM between tokens).
 
 def _fused_kernel_rows():
-    from repro import hw
     from repro.core.packing import pack
     from repro.core.sparsity import row_balanced_mask
     from repro.kernels import fused_brds_lstm_step, fused_brds_lstm_scan
@@ -217,7 +217,9 @@ def _fused_kernel_rows():
 # jax locks the device count at first init, so the sharded measurements run
 # in a child process with XLA_FLAGS=--xla_force_host_platform_device_count=8
 # (same pattern as tests/test_distributed.py); the parent re-emits the
-# child's CSV rows so they land in BENCH_decode_throughput.json too.
+# child's CSV rows so they land in BENCH_decode_throughput.json too. The
+# child is pinned to the CPU (JAX_PLATFORMS=cpu): the parent has touched
+# JAX, so on a chip machine it holds the chip, and its rows say so.
 
 _MESHES = ((1, 8), (2, 4))
 
@@ -238,11 +240,12 @@ def _sharded_child():
             packed, _ = eng.prepare(params)
             t = _time(lambda: eng.generate(packed, prompt, G))
             row(f"decode_packed_sharded_mesh{d}x{m}", t / toks * 1e6,
-                f"toks_per_s={toks / t:.0f} devices=8")
+                f"toks_per_s={toks / t:.0f} devices=8 platform="
+                f"{jax.devices()[0].platform}")
 
 
 def _sharded_rows():
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -122,11 +122,13 @@ def main():
             f"occupancy_x={occ['occupancy_x']:.4f}")
 
         # ---- the scorecard itself, from the enabled run's harvest -----
+        bound = (f"bound_effective_gops={card['bound_effective_gops']:.1f}"
+                 f" roofline_gap={card['roofline_gap']:.1f}x"
+                 if "roofline_gap" in card else
+                 f"roofline=none({card['device_kind']})")
         row("obs_scorecard", en[0] / en[1] * 1e6,
-            f"effective_gops={card['effective_gops']:.4f} "
-            f"bound_effective_gops={card['bound_effective_gops']:.1f} "
-            f"bytes_per_token={card['bytes_per_token']} "
-            f"roofline_gap={card['roofline_gap']:.1f}x")
+            f"effective_gops={card['effective_gops']:.4f} {bound} "
+            f"bytes_per_token={card['bytes_per_token']}")
 
 
 if __name__ == "__main__":

@@ -15,6 +15,8 @@ from . import common
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import decode_throughput, fig4_dual_ratio, fig9_patterns, \
         fig_delta_occupancy, fig_quant_tradeoff, obs, pipeline, spec, \
         table1_resources, table2_throughput, traffic

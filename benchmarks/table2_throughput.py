@@ -48,8 +48,9 @@ def main():
     bytes_sparse = sum(s.memory_bytes()["values"] // 2  # →16-bit values
                        + s.memory_bytes()["indices"]
                        for s in (packed[0]["sx"], packed[0]["sh"]))
-    t_dense = bytes_dense / hw.HBM_BW
-    t_sparse = bytes_sparse / hw.HBM_BW
+    bw = hw.peaks(hw.TARGET_KIND).hbm_bytes_per_s
+    t_dense = bytes_dense / bw
+    t_sparse = bytes_sparse / bw
     row("table2_v5e_model_dense", t_dense * 1e6,
         f"bytes={bytes_dense} effGOPS={ops/t_dense/1e9:.0f}")
     row("table2_v5e_model_sparse", t_sparse * 1e6,

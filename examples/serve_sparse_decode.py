@@ -78,9 +78,10 @@ def serve_transformer():
     dense_bytes = n * 2
     packed_bytes = n * (1 - rep["sparsity"]) * 2 \
         + n * (1 - rep["sparsity"]) * 1          # values + int8 deltas
+    bw = hw.peaks(hw.TARGET_KIND).hbm_bytes_per_s
     print(f"v5e per-token weight traffic: dense {dense_bytes/1e9:.1f} GB "
-          f"({dense_bytes/hw.HBM_BW*1e3:.2f} ms), packed "
-          f"{packed_bytes/1e9:.1f} GB ({packed_bytes/hw.HBM_BW*1e3:.2f} ms) "
+          f"({dense_bytes/bw*1e3:.2f} ms), packed "
+          f"{packed_bytes/1e9:.1f} GB ({packed_bytes/bw*1e3:.2f} ms) "
           f"→ {dense_bytes/packed_bytes:.1f}x decode speedup headroom")
     return model, cfg, params
 

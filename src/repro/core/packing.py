@@ -5,7 +5,7 @@ matrix has exactly K non-zeros, so values pack densely into a (rows, K)
 array. Column positions are stored with the paper's *relative addressing*
 (EIE-style [22]): the delta between consecutive non-zero column indices in a
 row, which fits a narrow integer type. The kernel reconstructs absolute
-columns with a cumulative sum in VMEM — index HBM traffic shrinks 2–4×
+columns with a prefix sum in VMEM — index HBM traffic shrinks 2–4×
 vs int32 absolute indices.
 
 This is a pytree, so it flows through jit/pjit/scan and can be sharded.
@@ -22,7 +22,7 @@ import numpy as np
 from .sparsity import row_balanced_mask, keep_count
 
 __all__ = ["RowBalancedSparse", "pack", "unpack", "pack_from_dense",
-           "pad_packed"]
+           "pad_packed", "block_rows_for"]
 
 
 @jax.tree_util.register_dataclass
@@ -133,6 +133,15 @@ def unpack(s: RowBalancedSparse) -> jnp.ndarray:
     return out.at[rowgrid, cols].set(s.values)
 
 
+def block_rows_for(rows: int, block_rows: int = 256) -> int:
+    """The kernel row block for a matrix of ``rows`` rows: ``block_rows``,
+    or — for a smaller matrix — its rows rounded up to a sublane multiple
+    of 8 (the kernels gather over 8-row tiles)."""
+    if not rows:
+        return block_rows
+    return min(block_rows, -(-rows // 8) * 8)
+
+
 def pad_packed(s, block_rows: int = 256):
     """Pre-pad a packed struct's row axis to a kernel-block multiple.
 
@@ -151,7 +160,7 @@ def pad_packed(s, block_rows: int = 256):
     or the struct is already padded for it.
     """
     r = s.rows
-    eff = min(block_rows, r) if r else block_rows
+    eff = block_rows_for(r, block_rows)
     pad = (-r) % eff
     if s.pad == pad and (s.block_rows in (None, eff) if pad == 0
                          else s.block_rows == eff):

@@ -25,7 +25,7 @@ continuous-batching scheduler's batch=1 prefills fall back to replicated
 batch); everything below is batch-elementwise, so data parallelism
 composes transparently with the model-axis row sharding.
 
-``check_rep=False`` throughout: the Pallas backend's ``pallas_call`` (and
+``check_vma=False`` throughout: the Pallas backend's ``pallas_call`` (and
 ``jax.lax.top_k`` inside the occupancy cap) defeat shard_map's static
 replication checker; replication of the h all-gather output holds by
 construction.
@@ -38,11 +38,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                    # jax >= 0.5
-    from jax.shard_map import shard_map as _shard_map
-except ImportError:                     # the 0.4.x line this repo targets
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from ..core.packing import RowBalancedSparse
 from ..kernels import ops as K
@@ -109,11 +104,11 @@ def sharded_rb_dual_spmv(mesh: Mesh, sx: RowBalancedSparse, x,
     def f(sx_, x_, sh_, h_, b_):
         return K.rb_dual_spmv(sx_, x_, sh_, h_, b_, backend=backend)
 
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(_packed_spec(sx), P(b, None), _packed_spec(sh),
                   P(b, None), P("model")),
-        out_specs=P(b, "model"), check_rep=False)(sx, x, sh, h, bias)
+        out_specs=P(b, "model"), check_vma=False)(sx, x, sh, h, bias)
 
 
 def sharded_delta_rb_dual_spmv(mesh: Mesh, sx: RowBalancedSparse, dx, fx,
@@ -129,11 +124,11 @@ def sharded_delta_rb_dual_spmv(mesh: Mesh, sx: RowBalancedSparse, dx, fx,
         return K.delta_rb_dual_spmv(sx_, dx_, fx_, sh_, dh_, fh_, m_,
                                     backend=backend)
 
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(_packed_spec(sx), P(b, None), P(b, None), _packed_spec(sh),
                   P(b, None), P(b, None), P(b, "model")),
-        out_specs=P(b, "model"), check_rep=False)(sx, dx, fx, sh, dh, fh, m)
+        out_specs=P(b, "model"), check_vma=False)(sx, dx, fx, sh, dh, fh, m)
 
 
 def sharded_rb_dual_spmv_q8(mesh: Mesh, sx: RowBalancedSparseQ8, x,
@@ -153,11 +148,11 @@ def sharded_rb_dual_spmv_q8(mesh: Mesh, sx: RowBalancedSparseQ8, x,
                                  act_scale_x=act_scale_x,
                                  act_scale_h=act_scale_h, backend=backend)
 
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(_packed_spec(sx), P(b, None), _packed_spec(sh),
                   P(b, None), P("model")),
-        out_specs=P(b, "model"), check_rep=False)(sx, x, sh, h, bias)
+        out_specs=P(b, "model"), check_vma=False)(sx, x, sh, h, bias)
 
 
 # ----------------------------------------------------- sharded decode steps
@@ -210,10 +205,10 @@ def dist_lstm_step(mesh: Mesh, layers, x_t, state, *, pwl: bool = False,
             inp = h2
         return inp, new
 
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(_layer_specs(layers), P(b, None), st_spec),
-        out_specs=(P(b, None), st_spec), check_rep=False)(
+        out_specs=(P(b, None), st_spec), check_vma=False)(
             layers, x_t, state)
 
 
@@ -264,8 +259,8 @@ def dist_delta_lstm_step(mesh: Mesh, layers, x_t, state, delta, *,
             inp = h2
         return inp, new
 
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(_layer_specs(layers), P(b, None), st_spec),
-        out_specs=(P(b, None), st_spec), check_rep=False)(
+        out_specs=(P(b, None), st_spec), check_vma=False)(
             layers, x_t, state)

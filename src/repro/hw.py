@@ -1,14 +1,43 @@
-"""Target-hardware constants (TPU v5e) used by the roofline analysis.
+"""Published per-chip peaks, keyed by ``jax.Device.device_kind``.
 
-This container executes on CPU; these numbers describe the TARGET chip that
-the dry-run artifacts are analysed against (per the assignment spec).
+Source for "TPU v5 lite" (the device kind JAX reports for a TPU v5e chip):
+Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s int8,
+16 GB of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect.
+
+A kind that is not in the table raises: no measurement is ever set
+against another chip's peaks by default. The dry-run roofline analyses
+its compiled artifacts against ``TARGET_KIND``, the chip this repository
+is built for.
 """
-PEAK_BF16_FLOPS = 197e12       # per chip, bf16
-PEAK_INT8_OPS = 394e12         # per chip, int8 MACs (2x the bf16 MXU rate)
-HBM_BW = 819e9                 # bytes/s per chip
-ICI_BW = 50e9                  # bytes/s per link (~)
-VMEM_BYTES = 128 * 1024 * 1024 # ~128 MiB VMEM per chip (v5e ~128MB)
-MXU_TILE = 128                 # systolic array dimension
-LANE = 128                     # vector lane width
-SUBLANE = 8                    # fp32 sublane count (16 for bf16)
-HBM_PER_CHIP = 16 * 2**30      # 16 GiB
+from __future__ import annotations
+
+import dataclasses
+
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    bf16_flops: float          # FLOP/s, bf16 MXU
+    int8_ops: float            # OP/s, int8 MXU
+    hbm_bytes_per_s: float
+    ici_link_bytes_per_s: float    # chip-to-chip interconnect, one link
+    source: str
+
+
+PEAKS = {
+    # 1,600 Gbit/s per chip over the 4 links of its 2D torus: 50 GB/s a link
+    "TPU v5 lite": Peaks(bf16_flops=197e12, int8_ops=393e12,
+                         hbm_bytes_per_s=819e9,
+                         ici_link_bytes_per_s=1600e9 / 8 / 4,
+                         source='Google Cloud documentation, "TPU v5e"'),
+}
+
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(kind: str) -> Peaks:
+    """The published peaks of one chip of ``kind``; KeyError if unknown."""
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {kind!r} "
+                       f"(known: {sorted(PEAKS)})") from None

@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the BRDS framework.
 
 Each kernel ships with a pure-jnp oracle in ref.py; ops.py holds the jit'd
-public wrappers (interpret=True on CPU, compiled on TPU).
+public wrappers (compiled on TPU; Pallas interpret mode elsewhere, decided
+once by ``ops.interpret_mode``).
 """
 from .ops import (
     rb_spmv,
@@ -20,6 +21,6 @@ from .ops import (
     fused_brds_delta_lstm_scan,
     flash_attention,
     decode_attention,
-    on_cpu,
+    interpret_mode,
 )
 from . import ref

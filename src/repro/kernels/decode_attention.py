@@ -55,7 +55,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 @functools.partial(jax.jit, static_argnames=("block_kv", "interpret"))
 def decode_attention(q, k, v, lengths, *, block_kv: int = 512,
-                     interpret: bool = True):
+                     interpret: bool):
     """q: (B, Hq, D); k, v: (B, Hkv, S, D); lengths: (B,) int32.
 
     Returns (B, Hq, D). The q heads of one kv group ride in the same tile

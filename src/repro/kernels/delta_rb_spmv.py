@@ -13,9 +13,9 @@ partial-sum memory ``m``:
   non-zeros per row, values + narrow delta-encoded column indices), so
   every grid step still does identical work per row — the balanced-PE
   invariant survives the temporal composition;
-- the activation side gathers BOTH the delta vector and its fired mask
-  from VMEM; a column that did not cross the threshold Θ contributes an
-  exact 0.0 to the accumulation — the product a real delta accelerator
+- the activation side masks the delta vector by its fired mask in VMEM
+  and gathers the product lane-locally (``rb_spmv.gather_dot``); a
+  column that did not cross the threshold Θ contributes an exact 0.0 to the accumulation — the product a real delta accelerator
   would never issue.  The occupancy (fired fraction) is the effective-ops
   metric `benchmarks/fig_delta_occupancy.py` sweeps;
 - the dual variant processes the W_x and W_h packed families in the SAME
@@ -35,23 +35,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .rb_spmv import DEF_BLOCK_ROWS
+from .rb_spmv import (DEF_BLOCK_ROWS, acc_scratch, dual_gate, dual_scratch,
+                      family_scratch, gather_dot, rows_spec, src_spec)
 
 
-def _delta_rb_spmv_kernel(d_ref, f_ref, vals_ref, deltas_ref, out_ref):
-    """Grid step: one block of rows. d/f (B, X); vals/deltas (bR, K);
+def _delta_rb_spmv_kernel(d_ref, f_ref, vals_ref, deltas_ref, out_ref,
+                          cols_scr, vals_scr, acc_scr, *, K):
+    """Grid step: one block of rows. d/f (B, Xp); vals/deltas (bR, Kp);
     out_ref (B, bR)."""
-    cols = jnp.cumsum(deltas_ref[...].astype(jnp.int32), axis=1)   # (bR, K)
-    dm = d_ref[...].astype(jnp.float32) * f_ref[...]               # (B, X)
-    g = jnp.take(dm, cols, axis=1)                                 # (B, bR, K)
-    v = vals_ref[...].astype(jnp.float32)                          # (bR, K)
-    acc = jnp.sum(g * v[None, :, :], axis=-1)                      # (B, bR)
+    dm = d_ref[...].astype(jnp.float32) * f_ref[...]               # (B, Xp)
+    acc = gather_dot(dm, vals_ref, deltas_ref, cols_scr, vals_scr, acc_scr,
+                     K=K, acc_dtype=jnp.float32)
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def delta_rb_spmv(values, deltas, d, fired, *,
-                  block_rows: int = DEF_BLOCK_ROWS, interpret: bool = True):
+                  block_rows: int = DEF_BLOCK_ROWS, interpret: bool):
     """y[b, r] = Σ_k values[r, k] · fired[b, c] · d[b, c], c = cols[r, k].
 
     values: (R, K) float; deltas: (R, K) int8/16/32; d: (B, X) raw
@@ -63,34 +63,27 @@ def delta_rb_spmv(values, deltas, d, fired, *,
     B, X = d.shape
     assert fired.shape == (B, X), (fired.shape, d.shape)
     assert R % block_rows == 0, (R, block_rows)
-    grid = (R // block_rows,)
     return pl.pallas_call(
-        _delta_rb_spmv_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((B, X), lambda i: (0, 0)),
-            pl.BlockSpec((B, X), lambda i: (0, 0)),
-            pl.BlockSpec((block_rows, K), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, K), lambda i: (i, 0)),
-        ],
+        functools.partial(_delta_rb_spmv_kernel, K=K),
+        grid=(R // block_rows,),
+        in_specs=[src_spec(B, X), src_spec(B, X), rows_spec(block_rows, K),
+                  rows_spec(block_rows, K)],
         out_specs=pl.BlockSpec((B, block_rows), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((B, R), d.dtype),
+        scratch_shapes=[*family_scratch(block_rows, K, jnp.float32),
+                        acc_scratch(B, block_rows, jnp.float32)],
         interpret=interpret,
     )(d, fired, values, deltas)
 
 
 def _delta_rb_dual_kernel(dx_ref, fx_ref, dh_ref, fh_ref, vx_ref, ix_ref,
-                          vh_ref, ih_ref, m_ref, out_ref):
+                          vh_ref, ih_ref, m_ref, out_ref, *scr, Kx, Kh):
     """One row block of m' = m + Sx@(fx·dx) + Sh@(fh·dh). Both packed
     families advance in the same step (Large/Small MA lockstep)."""
-    colsx = jnp.cumsum(ix_ref[...].astype(jnp.int32), axis=1)
-    colsh = jnp.cumsum(ih_ref[...].astype(jnp.int32), axis=1)
     dx = dx_ref[...].astype(jnp.float32) * fx_ref[...]
     dh = dh_ref[...].astype(jnp.float32) * fh_ref[...]
-    gx = jnp.take(dx, colsx, axis=1)                               # (B,bR,Kx)
-    gh = jnp.take(dh, colsh, axis=1)                               # (B,bR,Kh)
-    accx = jnp.sum(gx * vx_ref[...].astype(jnp.float32)[None], axis=-1)
-    acch = jnp.sum(gh * vh_ref[...].astype(jnp.float32)[None], axis=-1)
+    accx, acch = dual_gate(dx, dh, vx_ref, ix_ref, vh_ref, ih_ref, scr,
+                           Kx=Kx, Kh=Kh)
     m = m_ref[...].astype(jnp.float32) + accx + acch
     out_ref[...] = m.astype(out_ref.dtype)
 
@@ -98,7 +91,7 @@ def _delta_rb_dual_kernel(dx_ref, fx_ref, dh_ref, fh_ref, vx_ref, ix_ref,
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def delta_rb_dual_spmv(vals_x, deltas_x, dx, fx, vals_h, deltas_h, dh, fh,
                        m, *, block_rows: int = DEF_BLOCK_ROWS,
-                       interpret: bool = True):
+                       interpret: bool):
     """m' = m + Sx @ (fx·dx) + Sh @ (fh·dh) for packed row-balanced
     Sx (R, Kx), Sh (R, Kh).
 
@@ -110,22 +103,16 @@ def delta_rb_dual_spmv(vals_x, deltas_x, dx, fx, vals_h, deltas_h, dh, fh,
     H = dh.shape[1]
     assert vals_h.shape[0] == R and m.shape == (B, R)
     assert R % block_rows == 0, (R, block_rows)
-    grid = (R // block_rows,)
     return pl.pallas_call(
-        _delta_rb_dual_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((B, X), lambda i: (0, 0)),
-            pl.BlockSpec((B, X), lambda i: (0, 0)),
-            pl.BlockSpec((B, H), lambda i: (0, 0)),
-            pl.BlockSpec((B, H), lambda i: (0, 0)),
-            pl.BlockSpec((block_rows, Kx), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, Kx), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, Kh), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, Kh), lambda i: (i, 0)),
-            pl.BlockSpec((B, block_rows), lambda i: (0, i)),
-        ],
+        functools.partial(_delta_rb_dual_kernel, Kx=Kx, Kh=Kh),
+        grid=(R // block_rows,),
+        in_specs=[src_spec(B, X), src_spec(B, X), src_spec(B, H),
+                  src_spec(B, H),
+                  rows_spec(block_rows, Kx), rows_spec(block_rows, Kx),
+                  rows_spec(block_rows, Kh), rows_spec(block_rows, Kh),
+                  pl.BlockSpec((B, block_rows), lambda i: (0, i))],
         out_specs=pl.BlockSpec((B, block_rows), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((B, R), m.dtype),
+        scratch_shapes=dual_scratch(B, block_rows, Kx, Kh),
         interpret=interpret,
     )(dx, fx, dh, fh, vals_x, deltas_x, vals_h, deltas_h, m)

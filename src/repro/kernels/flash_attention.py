@@ -79,7 +79,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     "causal", "window", "block_q", "block_kv", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     block_q: int = 256, block_kv: int = 256,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D); Hq % Hkv == 0.
 
     Returns (B, Hq, Sq, D) in q.dtype. Sq/Sk must divide by the block sizes

@@ -68,7 +68,7 @@ def _lstm_gates_kernel(lut_ref, zf_ref, zi_ref, zg_ref, zo_ref, c_ref,
 
 @functools.partial(jax.jit, static_argnames=("pwl", "block", "interpret"))
 def lstm_gates(zf, zi, zg, zo, c_prev, *, pwl: bool = False,
-               block: int = DEF_BLOCK, interpret: bool = True):
+               block: int = DEF_BLOCK, interpret: bool):
     """Fused elementwise LSTM cell. All inputs (B, H); returns (c_t, h_t)."""
     B, H = zf.shape
     block = min(block, H)

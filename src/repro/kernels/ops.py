@@ -1,10 +1,14 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Backend selection goes through ``repro.sparse.backend``: "pallas" runs the
-kernels (interpret mode on CPU, compiled on TPU), "ref" the pure-jnp
-reference formulations (the dry-run path lowers these; XLA fuses them),
-"auto"/None the configured default. The old per-call ``use_kernel=``
-boolean is accepted as a deprecated alias.
+kernels, "ref" the pure-jnp reference formulations (the dry-run path
+lowers these; XLA fuses them), "auto"/None the configured default. The old
+per-call ``use_kernel=`` boolean is accepted as a deprecated alias.
+
+Whether a kernel runs compiled or in Pallas interpret mode is decided in
+ONE place, ``interpret_mode``: compiled on a TPU default backend, always;
+interpreted elsewhere (the CPU test runs). The kernels themselves take
+``interpret`` as a required argument, so no call can fall back silently.
 
 Row padding to kernel-block multiples is handled here, with a fast path
 for structs pre-padded by ``core.packing.pad_packed`` (the model/serving
@@ -28,13 +32,15 @@ from .rb_spmv_q8 import (rb_spmv_q8 as _rb_spmv_q8_kernel,
 from .lstm_gates import lstm_gates as _lstm_gates_kernel
 from .flash_attention import flash_attention as _flash_kernel
 from .decode_attention import decode_attention as _decode_kernel
-from ..core.packing import RowBalancedSparse
+from ..core.packing import RowBalancedSparse, block_rows_for
 from ..quant.scheme import quantize as _quantize
 from ..sparse import backend as _backend
 
 
-def on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+def interpret_mode() -> bool:
+    """Pallas interpret mode iff the default backend is not a TPU: the TPU
+    always runs the compiled kernels."""
+    return jax.default_backend() != "tpu"
 
 
 def _resolve(backend: str | None, use_kernel: bool | None) -> str:
@@ -56,14 +62,15 @@ def _prep_rows(s, block_rows):
     """→ (values, deltas, scales | None, eff_block, padded_rows).
 
     The padded row count is a pure function of (logical rows, block):
-    ``Rp = R + (-R) % min(block_rows, R)`` — so the two structs of a dual
+    ``Rp = R + (-R) % block_rows_for(R, block_rows)`` — so the two structs
+    of a dual
     call always agree. Fast path: the struct was pre-padded to exactly
     that count by ``core.packing.pad_packed`` (or needs no padding) and
     its arrays are consumed as-is, no per-call copy. Otherwise fall back
     to slicing to logical rows and padding here.
     """
     R = s.rows
-    eff = min(block_rows, R) if R else block_rows
+    eff = block_rows_for(R, block_rows)
     Rp = R + (-R) % eff
     scales = getattr(s, "scales", None)
     if s.values.shape[0] == Rp:
@@ -99,7 +106,8 @@ def rb_spmv(s: RowBalancedSparse, x: jnp.ndarray, *, block_rows: int = 256,
         return _ref.rb_spmv_ref(s, x)
     R = s.rows
     vals, deltas, _, eff, Rp = _prep_rows(s, block_rows)
-    y = _rb_spmv_kernel(vals, deltas, x, block_rows=eff, interpret=on_cpu())
+    y = _rb_spmv_kernel(vals, deltas, x, block_rows=eff,
+                        interpret=interpret_mode())
     return y[:, :R] if Rp > R else y
 
 
@@ -113,7 +121,7 @@ def rb_dual_spmv(sx: RowBalancedSparse, x, sh: RowBalancedSparse, h, bias,
     vx, dx, _, eff, Rp = _prep_rows(sx, block_rows)
     vh, dh, _, _, _ = _prep_rows(sh, block_rows)
     z = _rb_dual_kernel(vx, dx, x, vh, dh, h, _fit(bias, Rp),
-                        block_rows=eff, interpret=on_cpu())
+                        block_rows=eff, interpret=interpret_mode())
     return z[:, :R] if Rp > R else z
 
 
@@ -129,7 +137,7 @@ def delta_rb_spmv(s: RowBalancedSparse, d, fired, *, block_rows: int = 256,
     R = s.rows
     vals, deltas, _, eff, Rp = _prep_rows(s, block_rows)
     y = _delta_rb_spmv_kernel(vals, deltas, d, fired, block_rows=eff,
-                              interpret=on_cpu())
+                              interpret=interpret_mode())
     return y[:, :R] if Rp > R else y
 
 
@@ -146,7 +154,7 @@ def delta_rb_dual_spmv(sx: RowBalancedSparse, dx, fx,
     vx, dxi, _, eff, Rp = _prep_rows(sx, block_rows)
     vh, dhi, _, _, _ = _prep_rows(sh, block_rows)
     z = _delta_rb_dual_kernel(vx, dxi, dx, fx, vh, dhi, dh, fh, _fit(m, Rp),
-                              block_rows=eff, interpret=on_cpu())
+                              block_rows=eff, interpret=interpret_mode())
     return z[:, :R] if Rp > R else z
 
 
@@ -179,7 +187,7 @@ def rb_spmv_q8(s, x, *, act_scale=None, block_rows: int = 256,
     vals, deltas, scales, eff, Rp = _prep_rows(s, block_rows)
     comb = (scales * sa).astype(jnp.float32)
     y = _rb_spmv_q8_kernel(vals, deltas, comb, qx, block_rows=eff,
-                           interpret=on_cpu())
+                           interpret=interpret_mode())
     return y[:, :R] if Rp > R else y
 
 
@@ -200,7 +208,8 @@ def _dual_parts_q8(sx, qx, sax, sh, qh, sah, block_rows):
     vx, dxi, cx, vh, dhi, ch, eff, Rp = _prep_parts_q8(sx, sax, sh, sah,
                                                        block_rows)
     zx, zh = _rb_dual_parts_q8_kernel(vx, dxi, cx, qx, vh, dhi, ch, qh,
-                                      block_rows=eff, interpret=on_cpu())
+                                      block_rows=eff,
+                                      interpret=interpret_mode())
     return (zx[:, :R], zh[:, :R]) if Rp > R else (zx, zh)
 
 
@@ -325,7 +334,8 @@ def fused_brds_lstm_step(sx: RowBalancedSparse, x, sh: RowBalancedSparse,
     vh, dh, _, _, _ = _prep_rows(sh, block_rows)
     return _fused.fused_brds_lstm_step(vx, dx, x, vh, dh, h_prev,
                                        _fit(bias, Rp), c_prev, pwl=pwl,
-                                       block_rows=eff, interpret=on_cpu())
+                                       block_rows=eff,
+                                       interpret=interpret_mode())
 
 
 def fused_brds_delta_lstm_step(sx: RowBalancedSparse, dx, fx,
@@ -352,7 +362,7 @@ def fused_brds_delta_lstm_step(sx: RowBalancedSparse, dx, fx,
     vh, dhi, _, _, _ = _prep_rows(sh, block_rows)
     c, h, m = _fused.fused_brds_delta_lstm_step(
         vx, dxi, dx, fx, vh, dhi, dh, fh, _fit(m_prev, Rp), _fit(bias, Rp),
-        c_prev, pwl=pwl, block_rows=eff, interpret=on_cpu())
+        c_prev, pwl=pwl, block_rows=eff, interpret=interpret_mode())
     return c, h, m[:, :R] if Rp > R else m
 
 
@@ -375,7 +385,7 @@ def fused_brds_lstm_step_q8(sx, x, sh, h_prev, bias, c_prev, *,
     return _fused.fused_brds_lstm_step_q8(vx, dxi, cx, qx, vh, dhi, ch, qh,
                                           _fit(bias, Rp), c_prev, pwl=pwl,
                                           block_rows=eff,
-                                          interpret=on_cpu())
+                                          interpret=interpret_mode())
 
 
 def fused_brds_delta_lstm_step_q8(sx, dx, fx, sh, dh, fh, m_prev, bias,
@@ -405,7 +415,7 @@ def fused_brds_delta_lstm_step_q8(sx, dx, fx, sh, dh, fh, m_prev, bias,
     c, h, m = _fused.fused_brds_delta_lstm_step_q8(
         vx, dxi, cx, qdx, vh, dhi, ch, qdh, _fit(m_prev, Rp),
         _fit(bias, Rp), c_prev, pwl=pwl, block_rows=eff,
-        interpret=on_cpu())
+        interpret=interpret_mode())
     return c, h, m[:, :R] if Rp > R else m
 
 
@@ -439,7 +449,8 @@ def fused_brds_lstm_scan(sx: RowBalancedSparse, xs, sh: RowBalancedSparse,
     vh, dh, _, _, _ = _prep_rows(sh, block_rows)
     return _fused.fused_brds_lstm_scan(vx, dx, xs, vh, dh, h0,
                                        _fit(bias, Rp), c0, pwl=pwl,
-                                       block_rows=eff, interpret=on_cpu())
+                                       block_rows=eff,
+                                       interpret=interpret_mode())
 
 
 def fused_brds_delta_lstm_scan(sx: RowBalancedSparse, xs,
@@ -480,7 +491,7 @@ def fused_brds_delta_lstm_scan(sx: RowBalancedSparse, xs,
     hs, c, xr, hr, m = _fused.fused_brds_delta_lstm_scan(
         vx, dxi, xs, vh, dhi, h0, c0, x_ref0, h_ref0, _fit(m0, Rp),
         _fit(bias, Rp), theta_x=float(theta_x), theta_h=float(theta_h),
-        pwl=pwl, block_rows=eff, interpret=on_cpu())
+        pwl=pwl, block_rows=eff, interpret=interpret_mode())
     return hs, c, xr, hr, m[:, :R] if Rp > R else m
 
 
@@ -491,24 +502,22 @@ def lstm_gates(zf, zi, zg, zo, c_prev, *, pwl: bool = False,
     if _resolve(backend, use_kernel) == "ref":
         return _ref.lstm_cell_ref(zf, zi, zg, zo, c_prev, pwl=pwl)
     B, H = zf.shape
-    for cand in (512, 256, 128, 64):
-        if H % cand == 0:
-            block = cand
-            break
-    else:
-        if H > 64:
-            # odd hidden sizes: pad to the nearest 64-multiple and slice
-            # (the _pad_rows convention) instead of one giant block = H
-            Hp = -(-H // 64) * 64
-            w = ((0, 0), (0, Hp - H))
-            c, h = _lstm_gates_kernel(
-                jnp.pad(zf, w), jnp.pad(zi, w), jnp.pad(zg, w),
-                jnp.pad(zo, w), jnp.pad(c_prev, w), pwl=pwl, block=64,
-                interpret=on_cpu())
-            return c[:, :H], h[:, :H]
-        block = H
-    return _lstm_gates_kernel(zf, zi, zg, zo, c_prev, pwl=pwl, block=block,
-                              interpret=on_cpu())
+    if H <= 128:
+        # one block spanning the whole (small) hidden axis
+        return _lstm_gates_kernel(zf, zi, zg, zo, c_prev, pwl=pwl, block=H,
+                                  interpret=interpret_mode())
+    # lane-aligned blocks: odd hidden sizes pad to the next 128-multiple
+    # and slice (the _pad_rows convention) instead of one giant block = H
+    Hp = -(-H // 128) * 128
+    block = next(b for b in (512, 256, 128) if Hp % b == 0)
+    if Hp == H:
+        return _lstm_gates_kernel(zf, zi, zg, zo, c_prev, pwl=pwl,
+                                  block=block, interpret=interpret_mode())
+    w = ((0, 0), (0, Hp - H))
+    c, h = _lstm_gates_kernel(
+        jnp.pad(zf, w), jnp.pad(zi, w), jnp.pad(zg, w), jnp.pad(zo, w),
+        jnp.pad(c_prev, w), pwl=pwl, block=block, interpret=interpret_mode())
+    return c[:, :H], h[:, :H]
 
 
 # ---------------------------------------------------------------- attention
@@ -524,7 +533,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
     bq = max(g for g in (block_q, 128, 64, 32, 16, 8, 1) if Sq % g == 0)
     bk = max(g for g in (block_kv, 128, 64, 32, 16, 8, 1) if Sk % g == 0)
     return _flash_kernel(q, k, v, causal=causal, window=window, block_q=bq,
-                         block_kv=bk, interpret=on_cpu())
+                         block_kv=bk, interpret=interpret_mode())
 
 
 def decode_attention(q, k, v, lengths, *, block_kv: int = 512,
@@ -534,4 +543,5 @@ def decode_attention(q, k, v, lengths, *, block_kv: int = 512,
         return _ref.decode_attention_ref(q, k, v, lengths)
     S = k.shape[2]
     bk = max(g for g in (block_kv, 256, 128, 64, 32, 16, 8, 1) if S % g == 0)
-    return _decode_kernel(q, k, v, lengths, block_kv=bk, interpret=on_cpu())
+    return _decode_kernel(q, k, v, lengths, block_kv=bk,
+                          interpret=interpret_mode())

@@ -1,33 +1,23 @@
 """Production mesh construction. A FUNCTION, not a module constant — importing
 this module never touches jax device state.
 
-Every mesh in the repo is built here: ``make_mesh`` is the one place that
-carries the jax-0.4.x compat shim (``axis_types=`` / ``jax.sharding.AxisType``
-only exist on jax >= 0.5), so callers — ServeEngine, the drivers, the
-distributed tests — never construct ``Mesh(...)`` ad hoc.
+Every mesh in the repo is built here, so callers — ServeEngine, the
+drivers, the distributed tests — never construct ``Mesh(...)`` ad hoc.
 """
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """jax ≥ 0.5 wants explicit Auto axis types; older jax (this container
-    ships 0.4.x) has neither the kwarg nor jax.sharding.AxisType."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is None:
-        return {}
-    return dict(axis_types=(at.Auto,) * n_axes)
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: tuple, axes: tuple):
-    """General mesh over the available devices (the one AxisType-shim site).
+    """General mesh over the available devices, every axis ``Auto``.
 
     ``shape``/``axes`` as for ``jax.make_mesh`` — e.g.
     ``make_mesh((8,), ("data",))`` or ``make_mesh((2, 4), ("data", "model"))``.
     """
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

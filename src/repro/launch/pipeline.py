@@ -439,6 +439,8 @@ def _parse_grid(spec: str) -> tuple:
 
 
 def main(argv=None) -> int:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="train -> prune -> retrain -> calibrate -> pack -> "
                     "serve, with perplexity as a gate")

@@ -171,6 +171,8 @@ def _obs_outputs(args, params, counters, wall_s, *, batch, step_sum=None,
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b",
                     help="transformer-zoo arch or lstm_ptb/lstm_timit/...")

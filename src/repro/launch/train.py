@@ -23,6 +23,8 @@ import numpy as np
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b")
     ap.add_argument("--smoke", action="store_true",

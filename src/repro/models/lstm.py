@@ -580,15 +580,22 @@ class LSTMModel:
         _, hs = jax.lax.scan(body, state0, x.transpose(1, 0, 2))
         hs = hs.transpose(1, 0, 2)
         logits = jnp.einsum("bth,hv->btv", hs.astype(jnp.float32),
-                            params["head"]["w"].astype(jnp.float32))
+                            params["head"]["w"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
         if cfg.vocab_size:
             return cross_entropy(logits[:, :-1], labels[:, 1:])
         return cross_entropy(logits, labels)
 
     def _head_logits(self, params, h):
-        """h (B, H) → logits (B, 1, V or C) fp32."""
+        """h (B, H) → logits (B, 1, V or C) fp32.
+
+        Full f32 precision: at the TPU's default, which rounds f32
+        operands to bf16, a row's greedy tokens on a v5e depended on the
+        batch it was served in, breaking the scheduler's parity with
+        batch-1 decode."""
         return jnp.einsum("bh,hv->bv", h.astype(jnp.float32),
-                          params["head"]["w"].astype(jnp.float32))[:, None]
+                          params["head"]["w"].astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)[:, None]
 
     def _embed_step(self, params, tokens):
         """tokens (B, 1) ids (LM) or (B, 1, X) features → x_t (B, X)."""

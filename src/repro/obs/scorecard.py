@@ -12,9 +12,11 @@ params into that figure and places it against the decode roofline:
   further scaled by delta occupancy when fired-column counters are
   present (exactly ``occupancy_report``'s MAC weighting: a fired column
   of family F costs rows_F · K_F / N_F MACs);
-- ``bound_toks_per_s`` = B · HBM_BW / weight-stream bytes — the
-  memory-roofline decode bound (`benchmarks/decode_throughput` idiom:
-  every decode step streams the packed recurrent weights once);
+- ``bound_toks_per_s`` = B · HBM bandwidth / weight-stream bytes — the
+  memory-roofline decode bound (every decode step streams the packed
+  recurrent weights once), with the bandwidth the published peak of the
+  device kind the run used (``hw.PEAKS``). A device with no published
+  peak (the CPU) gets no roofline lines rather than another chip's;
 - ``bound_effective_gops`` / ``roofline_gap`` place the run against that
   bound on the same effective axis;
 - ``bytes_per_token`` = weight-stream bytes (per lockstep row-step the
@@ -26,6 +28,8 @@ LM head are excluded from both the MAC and the byte ledger on every
 line, so ratios stay apples-to-apples.
 """
 from __future__ import annotations
+
+import jax
 
 from .. import hw
 from . import counters as _counters
@@ -80,7 +84,8 @@ def weight_stream_bytes(params) -> int:
 
 def build(params, counters: dict, wall_s: float, *, batch: int = 1,
           bytes_per_step: int | None = None,
-          step_sum: float | None = None) -> dict:
+          step_sum: float | None = None,
+          device_kind: str | None = None) -> dict:
     """One serve run's scorecard.
 
     Parameters
@@ -106,6 +111,10 @@ def build(params, counters: dict, wall_s: float, *, batch: int = 1,
         steps — ``sched.slot_steps.sum()`` for the scheduler,
         B·(prompt+generated) for a lockstep run). Enables the occupancy
         lines; without it they are omitted rather than guessed.
+    device_kind : str, optional
+        Device kind whose published HBM bandwidth bounds the run
+        (``hw.PEAKS``); defaults to the kind of ``jax.devices()[0]``. A
+        kind with no published peaks gets no roofline lines.
     """
     geo = layer_geometry(params)
     dense_macs = sum(g["dense_macs"] for g in geo)
@@ -128,7 +137,8 @@ def build(params, counters: dict, wall_s: float, *, batch: int = 1,
 
     nbytes = int(bytes_per_step if bytes_per_step is not None
                  else weight_stream_bytes(params))
-    bound_toks = batch * hw.HBM_BW / max(nbytes, 1)
+    kind = (device_kind if device_kind is not None
+            else jax.devices()[0].device_kind)
     out = {
         "tokens": int(tokens),
         "decode_steps": int(steps),
@@ -140,12 +150,17 @@ def build(params, counters: dict, wall_s: float, *, batch: int = 1,
         "achieved_gops": round(2.0 * executed_macs / wall_s / 1e9, 6),
         "effective_gops": round(2.0 * dense_macs * tokens / wall_s / 1e9, 6),
         "bytes_per_token": nbytes,
-        "bound_toks_per_s": round(bound_toks, 1),
-        "bound_effective_gops": round(2.0 * dense_macs * bound_toks / 1e9,
-                                      3),
-        "roofline_gap": round(bound_toks / max(toks_per_s, 1e-12), 2),
-        "bound": "memory",
+        "device_kind": kind,
     }
+    if kind in hw.PEAKS:
+        bound_toks = batch * hw.peaks(kind).hbm_bytes_per_s / max(nbytes, 1)
+        out.update({
+            "bound_toks_per_s": round(bound_toks, 1),
+            "bound_effective_gops": round(
+                2.0 * dense_macs * bound_toks / 1e9, 3),
+            "roofline_gap": round(bound_toks / max(toks_per_s, 1e-12), 2),
+            "bound": "memory",
+        })
     if counters.get("spec_drafted"):
         out["spec_acceptance_rate"] = round(
             counters["spec_accepted"] / counters["spec_drafted"], 4)
@@ -167,11 +182,16 @@ def render(card: dict) -> str:
         f"(dense-equiv {card['dense_macs_per_token']} MACs/token)",
         f"  achieved GOPS {card['achieved_gops']:.3f} "
         f"(executed {card['executed_macs']:.3e} MACs)",
-        f"  roofline bound {card['bound_toks_per_s']:.0f} tok/s "
-        f"= {card['bound_effective_gops']:.1f} effective GOPS "
-        f"({card['bound']}-bound, {card['bytes_per_token']} B/token) "
-        f"-> gap {card['roofline_gap']:.1f}x",
     ]
+    if "bound_toks_per_s" in card:
+        lines.append(
+            f"  roofline bound {card['bound_toks_per_s']:.0f} tok/s "
+            f"= {card['bound_effective_gops']:.1f} effective GOPS "
+            f"({card['bound']}-bound, {card['bytes_per_token']} B/token) "
+            f"-> gap {card['roofline_gap']:.1f}x")
+    else:
+        lines.append(f"  roofline: no published peaks for device kind "
+                     f"{card['device_kind']!r}")
     if "occupancy_x" in card:
         lines.append(f"  delta occupancy x={card['occupancy_x']:.1%} "
                      f"h={card['occupancy_h']:.1%}")

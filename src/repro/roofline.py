@@ -310,7 +310,7 @@ def dot_flops(comps, mult, int_only: bool = False) -> float:
 
 def int8_dot_flops(comps, mult) -> float:
     """The integer-dot subset of ``dot_flops``, costed at
-    ``hw.PEAK_INT8_OPS`` by the roofline terms. (int16 fixed-point dots
+    the int8 peak by the roofline terms. (int16 fixed-point dots
     are approximated at the same rate — the quantized path's dominant
     deployment is int8.)"""
     return dot_flops(comps, mult, int_only=True)
@@ -331,12 +331,13 @@ class RooflineReport:
         # post-SPMD HLO shapes are PER-DEVICE, so parsed flops / wire bytes
         # are already per-chip quantities. int8 dots run at the int8 MXU
         # peak (2x bf16) — costing a quantized model at the bf16 rate would
-        # overstate its compute time.
-        compute_s = ((self.flops_hlo - self.flops_int8)
-                     / hw.PEAK_BF16_FLOPS
-                     + self.flops_int8 / hw.PEAK_INT8_OPS)
-        memory_s = hbm_bytes_per_chip / hw.HBM_BW
-        coll_s = self.collective_wire_bytes / hw.ICI_BW
+        # overstate its compute time. Ring wire bytes may all cross one
+        # link, so the collective term uses the per-link bandwidth.
+        pk = hw.peaks(hw.TARGET_KIND)
+        compute_s = ((self.flops_hlo - self.flops_int8) / pk.bf16_flops
+                     + self.flops_int8 / pk.int8_ops)
+        memory_s = hbm_bytes_per_chip / pk.hbm_bytes_per_s
+        coll_s = self.collective_wire_bytes / pk.ici_link_bytes_per_s
         dom = max(compute_s, memory_s, coll_s)
         which = ("compute" if dom == compute_s else
                  "memory" if dom == memory_s else "collective")

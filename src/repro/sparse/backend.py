@@ -3,11 +3,12 @@
 One switch replaces every ``use_kernel=`` / ``interpret=`` flag that used to
 be threaded through call sites:
 
-  "pallas" — the Pallas kernels (interpret mode on CPU, compiled on TPU)
+  "pallas" — the Pallas kernels: compiled on a TPU, Pallas interpret mode
+             off it (``kernels.ops.interpret_mode`` decides, in one place)
   "ref"    — the pure-jnp reference formulations (XLA fuses them; this is
              what the dry-run lowers)
-  "auto"   — resolve to "pallas" (the kernels themselves fall back to
-             interpret mode off-TPU, so "auto" is always safe)
+  "auto"   — resolve to "pallas". Never to "ref": the reference path runs
+             only when a caller asks for it by name
 
 The default is configured once — on a ``SparsityPolicy``/``SparsityPlan``,
 on a format call, or process-wide with ``set_default_backend`` /
@@ -57,8 +58,7 @@ def resolve(backend: str | None = None) -> str:
         Per-call request. None and "auto" both defer to the configured
         process default, so ``set_default_backend``/``use_backend`` reach
         every policy/plan left at backend="auto". A default of "auto"
-        means "let the system pick" → "pallas" (the kernels run
-        interpreted on CPU, so this is always safe).
+        resolves to "pallas"; "ref" is returned only when asked for.
 
     Returns
     -------

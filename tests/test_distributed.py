@@ -66,9 +66,8 @@ def test_compressed_psum_matches_exact():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.training.compression import compressed_psum
-    from repro.launch.mesh import make_mesh   # owns the AxisType shim
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((8,), ('data',))
     g = jnp.asarray(np.random.default_rng(0).normal(size=(8, 64)), jnp.float32)
@@ -77,8 +76,8 @@ def test_compressed_psum_matches_exact():
         mean, new_res = compressed_psum(gl[0], 'data', res[0])
         return mean[None], new_res[None]
 
-    sm = shard_map(f, mesh=mesh, in_specs=(P('data'), P('data')),
-                   out_specs=(P('data'), P('data')))
+    sm = jax.shard_map(f, mesh=mesh, in_specs=(P('data'), P('data')),
+                       out_specs=(P('data'), P('data')))
     res = jnp.zeros((8, 64), jnp.float32)
     mean_c, res = sm(g, res)
     exact = jnp.mean(g, axis=0)

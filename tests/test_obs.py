@@ -308,6 +308,7 @@ def test_lockstep_from_state_matches_occupancy_report():
 # -------------------------------------------------------------- scorecard
 def test_scorecard_geometry_and_bounds_dense():
     from repro import hw
+    kind = "TPU v5 lite"                    # checks the formula on any host
     model = LSTMModel(CFG)
     params = model.init(jax.random.key(0))
     geo = S.layer_geometry(params)
@@ -321,13 +322,13 @@ def test_scorecard_geometry_and_bounds_dense():
                          for i in range(CFG.num_layers)
                          for k in ("w_x", "w_h"))
     card = S.build(params, {"tokens": 100.0, "decode_steps": 100.0},
-                   wall_s=2.0, batch=4)
+                   wall_s=2.0, batch=4, device_kind=kind)
     assert card["toks_per_s"] == 50.0
     assert card["executed_macs"] == 100.0 * dense   # no fired gauges
     assert card["effective_gops"] == pytest.approx(
         2.0 * dense * 50.0 / 1e9, abs=1e-6)       # card rounds to 6 dp
     assert card["bound_toks_per_s"] == pytest.approx(
-        4 * hw.HBM_BW / nbytes, rel=1e-3)
+        4 * hw.peaks(kind).hbm_bytes_per_s / nbytes, rel=1e-3)
     assert "occupancy_x" not in card                # needs step_sum
     text = S.render(card)
     assert "effective GOPS" in text and "roofline bound" in text

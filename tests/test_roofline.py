@@ -43,7 +43,7 @@ def test_scan_trip_count_multiplies_flops():
 def test_int8_dots_counted_at_int8_peak():
     """Quantized dots — s8 operands (TPU builds) or the s32-accumulator
     form XLA CPU normalizes them to — land in the int8 bucket and are
-    costed at hw.PEAK_INT8_OPS, not the bf16 peak; float dots stay in the
+    costed at the int8 peak, not the bf16 peak; float dots stay in the
     bf16 bucket. Keeps the quant benchmark's derived GOPS honest."""
     from repro import hw
 
@@ -77,7 +77,8 @@ def test_int8_dots_counted_at_int8_peak():
         assert rep.flops_hlo == pytest.approx(2 * one_dot)
         assert rep.flops_int8 == pytest.approx(one_dot)
         t = rep.terms(hbm_bytes_per_chip=0, chips=1)
-        expect = one_dot / hw.PEAK_BF16_FLOPS + one_dot / hw.PEAK_INT8_OPS
+        pk = hw.peaks(hw.TARGET_KIND)
+        expect = one_dot / pk.bf16_flops + one_dot / pk.int8_ops
         assert t["compute_s"] == pytest.approx(expect)
 
 

@@ -56,6 +56,25 @@ def test_lstm_dense_vs_packed_serving_parity(lstm):
     np.testing.assert_array_equal(out_dense, out_packed)
 
 
+def test_lstm_head_dot_pins_full_precision(lstm):
+    """The dense head dot is lowered at HIGHEST precision in prefill and
+    decode, so on a TPU a row's logits do not depend on its batch."""
+    cfg, model, params = lstm
+    prompt = jnp.zeros((3, 4), jnp.int32)
+    cache = model.init_cache(3, 8)
+    texts = [
+        jax.jit(model.prefill, static_argnames=("max_len",))
+        .lower(params, prompt, max_len=8).as_text(),
+        jax.jit(model.decode_step)
+        .lower(params, cache, prompt[:, :1], 0).as_text(),
+    ]
+    for text in texts:
+        head = [ln for ln in text.splitlines()
+                if "dot_general" in ln and f"x{cfg.vocab_size}xf32>" in ln]
+        assert head and all("precision = [HIGHEST, HIGHEST]" in ln
+                            for ln in head), head
+
+
 def test_engine_prepare_packs_lstm(lstm):
     """prepare() on a packed-decode model prunes AND packs."""
     from repro.core.packing import RowBalancedSparse
@@ -272,8 +291,12 @@ def test_slot_reuse_resets_position_and_eos(lstm):
     p_a = jax.random.randint(jax.random.key(20), (1, 5), 0, cfg.vocab_size)
     p_b = jax.random.randint(jax.random.key(21), (1, 9), 0, cfg.vocab_size)
     greedy_a = np.asarray(eng.generate(params, p_a, 8))[0]
-    eos = int(greedy_a[1])                  # A hits EOS on its 2nd token,
-    sampling = SamplingConfig(eos_id=eos)   # mid-chunk (chunk=4 below)
+    # EOS := the first token of A's greedy stream that has not appeared
+    # before it, inside the first chunk but not at its end (chunk=4
+    # below) — so A stops exactly there, mid-chunk, whatever the stream
+    stop = next(i for i in range(1, 3) if greedy_a[i] not in greedy_a[:i])
+    eos = int(greedy_a[stop])
+    sampling = SamplingConfig(eos_id=eos)
 
     sched = ContinuousBatchingEngine(model, params, slots=1, max_len=24,
                                      chunk=4, sampling=sampling)
@@ -290,7 +313,8 @@ def test_slot_reuse_resets_position_and_eos(lstm):
     want_b = np.asarray(eng.generate(params, p_b, 6, sampling=sampling))[0]
     np.testing.assert_array_equal(results[uid_b], want_b)
     # A's tokens end at EOS and the readmit reset the slot's accounting
-    assert int(results[uid_a][-1]) == eos and len(results[uid_a]) == 2
+    assert int(results[uid_a][-1]) == eos
+    assert len(results[uid_a]) == stop + 1
     assert sched.slot_steps[0] >= p_b.shape[1]  # restarted at B's join
 
 

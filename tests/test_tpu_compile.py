@@ -1,0 +1,152 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e chip.
+
+Interpret mode lowers kernels through XLA and never reaches the TPU's
+Pallas compiler (Mosaic), so it cannot catch a primitive Mosaic has no
+lowering for, a gather across vregs, a misaligned block or a VMEM
+overflow. These tests compile each decode kernel at the paper's published
+widths — ``lstm_ptb`` (X=H=1500) and ``lstm_timit`` (X=153, H=1024), B=8
+(and B=1, 4 at ``lstm_ptb``),
+at the serve defaults Spar_x=0.75 / Spar_h=0.5 — for one chip of a
+``v5e:2x2`` topology that is described, not attached, and assert that
+each compiled program holds the Mosaic kernel (``tpu_custom_call``).
+
+The topology is described inside a module fixture, never at import: only
+one process may hold the TPU library, and the test workers import every
+test file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.packing import block_rows_for
+from repro.core.sparsity import keep_count
+from repro.kernels import fused_step
+from repro.kernels.delta_rb_spmv import delta_rb_dual_spmv
+from repro.kernels.lstm_gates import lstm_gates
+from repro.kernels.rb_spmv import rb_dual_spmv
+from repro.kernels.rb_spmv_q8 import rb_dual_parts_q8
+
+T = 8
+SPAR_X, SPAR_H = 0.75, 0.5
+WIDTHS = {"lstm_ptb": (1500, 1500), "lstm_timit": (153, 1024)}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to compile for
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one — keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _shapes(arch):
+    """Kernel operand shapes at ``arch``'s widths, rows padded to the
+    kernel block as ``core.packing.pad_packed`` pads them."""
+    X, H = WIDTHS[arch]
+    R = 4 * H
+    block = block_rows_for(R)
+    Rp = R + (-R) % block
+    return dict(X=X, H=H, R=Rp, block=block, Kx=keep_count(X, SPAR_X),
+                Kh=keep_count(H, SPAR_H))
+
+
+def _args(sh, kind, B):
+    """(function, operand (shape, dtype) list) for one kernel kind."""
+    X, H, R, Kx, Kh = sh["X"], sh["H"], sh["R"], sh["Kx"], sh["Kh"]
+    f32, i8, i16 = jnp.float32, jnp.int8, jnp.int16
+    kw = dict(block_rows=sh["block"], interpret=False)
+    fam = lambda K, vdt=f32: [((R, K), vdt), ((R, K), i16)]
+    fam_q8 = lambda K: [((R, K), i8), ((R, K), i16), ((R,), f32)]
+    if kind == "dual_spmv":
+        return (functools.partial(rb_dual_spmv, **kw),
+                fam(Kx) + [((B, X), f32)] + fam(Kh)
+                + [((B, H), f32), ((R,), f32)])
+    if kind == "delta_dual_spmv":
+        return (functools.partial(delta_rb_dual_spmv, **kw),
+                fam(Kx) + [((B, X), f32)] * 2 + fam(Kh)
+                + [((B, H), f32)] * 2 + [((B, R), f32)])
+    if kind == "q8_dual_parts":
+        return (functools.partial(rb_dual_parts_q8, **kw),
+                fam_q8(Kx) + [((B, X), i8)] + fam_q8(Kh) + [((B, H), i8)])
+    if kind == "lstm_gates":
+        Hp = -(-H // 128) * 128        # kernels.ops.lstm_gates' padding
+        block = next(b for b in (512, 256, 128) if Hp % b == 0)
+        return (functools.partial(lstm_gates, block=block,
+                                  interpret=False),
+                [((B, Hp), f32)] * 5)
+    if kind == "fused_step":
+        return (functools.partial(fused_step.fused_brds_lstm_step, **kw),
+                fam(Kx) + [((B, X), f32)] + fam(Kh)
+                + [((B, H), f32), ((R,), f32), ((B, H), f32)])
+    if kind == "fused_delta_step":
+        return (functools.partial(fused_step.fused_brds_delta_lstm_step,
+                                  **kw),
+                fam(Kx) + [((B, X), f32)] * 2 + fam(Kh)
+                + [((B, H), f32)] * 2
+                + [((B, R), f32), ((R,), f32), ((B, H), f32)])
+    if kind == "fused_q8_step":
+        return (functools.partial(fused_step.fused_brds_lstm_step_q8, **kw),
+                fam_q8(Kx) + [((B, X), i8)] + fam_q8(Kh)
+                + [((B, H), i8), ((R,), f32), ((B, H), f32)])
+    if kind == "fused_delta_q8_step":
+        return (functools.partial(fused_step.fused_brds_delta_lstm_step_q8,
+                                  **kw),
+                fam_q8(Kx) + [((B, X), i8)] + fam_q8(Kh)
+                + [((B, H), i8), ((B, R), f32), ((R,), f32), ((B, H), f32)])
+    if kind == "fused_scan":
+        return (functools.partial(fused_step.fused_brds_lstm_scan, **kw),
+                fam(Kx) + [((T, B, X), f32)] + fam(Kh)
+                + [((B, H), f32), ((R,), f32), ((B, H), f32)])
+    if kind == "fused_delta_scan":
+        return (functools.partial(fused_step.fused_brds_delta_lstm_scan,
+                                  theta_x=0.05, theta_h=0.05, **kw),
+                fam(Kx) + [((T, B, X), f32)] + fam(Kh)
+                + [((B, H), f32)] * 2 + [((B, X), f32), ((B, H), f32),
+                                         ((B, R), f32), ((R,), f32)])
+    raise ValueError(kind)
+
+
+KINDS = ("dual_spmv", "delta_dual_spmv", "q8_dual_parts", "lstm_gates",
+         "fused_step", "fused_delta_step", "fused_q8_step",
+         "fused_delta_q8_step", "fused_scan", "fused_delta_scan")
+
+
+# B=8 is the lockstep batch; B=1 the scheduler's exact-length prefill and
+# B=4 its slot batch — a batch below one 8-row sublane tile lays rows out
+# differently, and Mosaic has refused such layouts where B=8 compiled.
+CASES = ([(arch, 8) for arch in sorted(WIDTHS)]
+         + [("lstm_ptb", 1), ("lstm_ptb", 4)])
+
+
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("arch,B", CASES)
+def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, arch, B, kind):
+    fn, operands = _args(_shapes(arch), kind, B)
+    structs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+               for s, d in operands]
+    compiled = jax.jit(fn).lower(*structs).compile()
+    assert "tpu_custom_call" in compiled.as_text()

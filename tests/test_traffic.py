@@ -173,6 +173,18 @@ def test_bucketing_compiles_once_per_bucket(lstm):
     real = model.prefill
 
     class Probe:
+        # the DecodeStep contract, spelled out: a runtime-checkable
+        # Protocol finds members statically (Python >= 3.12), so
+        # __getattr__ delegation alone would not conform
+        def cache_defs(self, batch, max_len):
+            return model.cache_defs(batch, max_len)
+
+        def init_cache(self, batch, max_len):
+            return model.init_cache(batch, max_len)
+
+        def decode_step(self, p, cache, tokens, pos):
+            return model.decode_step(p, cache, tokens, pos)
+
         def __getattr__(self, name):
             return getattr(model, name)
 
