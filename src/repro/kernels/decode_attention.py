@@ -87,5 +87,6 @@ def decode_attention(q, k, v, lengths, *, block_kv: int = 512,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(lengths.astype(jnp.int32), qg, k, v)
     return out.reshape(B, Hq, D)

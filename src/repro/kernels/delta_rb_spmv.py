@@ -73,6 +73,7 @@ def delta_rb_spmv(values, deltas, d, fired, *,
         scratch_shapes=[*family_scratch(block_rows, K, jnp.float32),
                         acc_scratch(B, block_rows, jnp.float32)],
         interpret=interpret,
+        name="delta_rb_spmv",
     )(d, fired, values, deltas)
 
 
@@ -115,4 +116,5 @@ def delta_rb_dual_spmv(vals_x, deltas_x, dx, fx, vals_h, deltas_h, dh, fh,
         out_shape=jax.ShapeDtypeStruct((B, R), m.dtype),
         scratch_shapes=dual_scratch(B, block_rows, Kx, Kh),
         interpret=interpret,
+        name="delta_rb_dual_spmv",
     )(dx, fx, dh, fh, vals_x, deltas_x, vals_h, deltas_h, m)

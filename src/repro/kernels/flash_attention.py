@@ -113,4 +113,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -157,6 +157,7 @@ def fused_brds_lstm_step(vals_x, deltas_x, x, vals_h, deltas_h, h, bias,
                         pltpu.VMEM((2, B, H), jnp.float32),
                         *dual_scratch(B, block_rows, Kx, Kh)],
         interpret=interpret,
+        name="fused_brds_lstm_step",
     )(_lut(), x, h, c_prev, vals_x, deltas_x, vals_h, deltas_h,
       bias.reshape(1, R))
     return c, h_out
@@ -224,6 +225,7 @@ def fused_brds_delta_lstm_step(vals_x, deltas_x, dx, fx, vals_h, deltas_h,
                         pltpu.VMEM((2, B, H), jnp.float32),
                         *dual_scratch(B, block_rows, Kx, Kh)],
         interpret=interpret,
+        name="fused_brds_delta_lstm_step",
     )(_lut(), dx, fx, dh, fh, c_prev, vals_x, deltas_x, vals_h, deltas_h,
       m, bias.reshape(1, R))
     return c, h, m_out
@@ -294,6 +296,7 @@ def fused_brds_lstm_step_q8(vals_x, deltas_x, scales_x, qx, vals_h, deltas_h,
                         pltpu.VMEM((2, B, H), jnp.float32),
                         *dual_scratch(B, block_rows, Kx, Kh, jnp.int32)],
         interpret=interpret,
+        name="fused_brds_lstm_step_q8",
     )(_lut(), qx, qh, c_prev, vals_x, deltas_x, scales_x.reshape(1, R),
       vals_h, deltas_h, scales_h.reshape(1, R), bias.reshape(1, R))
     return c, h
@@ -359,6 +362,7 @@ def fused_brds_delta_lstm_step_q8(vals_x, deltas_x, scales_x, qdx, vals_h,
                         pltpu.VMEM((2, B, H), jnp.float32),
                         *dual_scratch(B, block_rows, Kx, Kh, jnp.int32)],
         interpret=interpret,
+        name="fused_brds_delta_lstm_step_q8",
     )(_lut(), qdx, qdh, c_prev, vals_x, deltas_x, scales_x.reshape(1, R),
       vals_h, deltas_h, scales_h.reshape(1, R), m, bias.reshape(1, R))
     return c, h, m_out
@@ -448,6 +452,7 @@ def fused_brds_lstm_scan(vals_x, deltas_x, xs, vals_h, deltas_h, h0, bias,
                         pltpu.VMEM((2, B, H), jnp.float32),
                         *dual_scratch(B, block_rows, Kx, Kh)],
         interpret=interpret,
+        name="fused_brds_lstm_scan",
     )(_lut(), xs, h0, c0, vals_x, deltas_x, vals_h, deltas_h,
       bias.reshape(1, R))
     return hs, c
@@ -561,6 +566,7 @@ def fused_brds_delta_lstm_scan(vals_x, deltas_x, xs, vals_h, deltas_h, h0,
                         pltpu.VMEM((2, B, H), jnp.float32),
                         *dual_scratch(B, block_rows, Kx, Kh)],
         interpret=interpret,
+        name="fused_brds_delta_lstm_scan",
     )(_lut(), xs, h0, c0, x_ref0, h_ref0, m0, vals_x, deltas_x, vals_h,
       deltas_h, bias.reshape(1, R))
     return hs, c, xr, hr, m

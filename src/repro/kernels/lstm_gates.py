@@ -85,5 +85,6 @@ def lstm_gates(zf, zi, zg, zo, c_prev, *, pwl: bool = False,
         out_shape=[jax.ShapeDtypeStruct((B, H), c_prev.dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((2, B, block), jnp.float32)],
         interpret=interpret,
+        name="lstm_gates",
     )(lut, zf, zi, zg, zo, c_prev)
     return c, h

@@ -183,6 +183,7 @@ def rb_spmv(values, deltas, x, *, block_rows: int = DEF_BLOCK_ROWS,
         scratch_shapes=[*family_scratch(block_rows, K, jnp.float32),
                         acc_scratch(B, block_rows, jnp.float32)],
         interpret=interpret,
+        name="rb_spmv",
     )(x, values, deltas)
 
 
@@ -239,4 +240,5 @@ def rb_dual_spmv(vals_x, deltas_x, x, vals_h, deltas_h, h, bias, *,
         out_shape=jax.ShapeDtypeStruct((B, R), x.dtype),
         scratch_shapes=dual_scratch(B, block_rows, Kx, Kh),
         interpret=interpret,
+        name="rb_dual_spmv",
     )(x, h, vals_x, deltas_x, vals_h, deltas_h, bias.reshape(1, R))

@@ -71,6 +71,7 @@ def rb_spmv_q8(values, deltas, scales, qx, *,
         scratch_shapes=[*family_scratch(block_rows, K, jnp.int32),
                         acc_scratch(B, block_rows, jnp.int32)],
         interpret=interpret,
+        name="rb_spmv_q8",
     )(qx, values, deltas, scales.reshape(1, R))
 
 
@@ -133,5 +134,6 @@ def rb_dual_parts_q8(vals_x, deltas_x, scales_x, qx, vals_h, deltas_h,
         out_shape=[jax.ShapeDtypeStruct((B, R), jnp.float32)] * 2,
         scratch_shapes=dual_scratch(B, block_rows, Kx, Kh, jnp.int32),
         interpret=interpret,
+        name="rb_dual_parts_q8",
     )(qx, qh, vals_x, deltas_x, scales_x.reshape(1, R), vals_h, deltas_h,
       scales_h.reshape(1, R))

@@ -2,9 +2,7 @@
 
 One global host-side tracer instruments the whole serving path
 (`ServeEngine` prepare/prefill/generate, the continuous-batching
-scheduler's admit/dispatch/harvest/evict, `repro.spec`'s
-propose/verify/rollback, and the `launch.pipeline` phases). The
-contract:
+scheduler and the `launch.pipeline` phases). The contract:
 
 - **Disabled is the default and costs (near) nothing.** ``span()`` on a
   disabled tracer returns one shared no-op context manager — a single
@@ -14,17 +12,34 @@ contract:
   or not (the golden-trajectory tests pin this).
 - **Spans are host-wall-clock.** Device work is asynchronous; a span
   around a dispatch measures the host's enqueue cost, a span around a
-  harvest measures the true sync wait. Spans placed inside jit-traced
-  code (e.g. the spec propose/verify/rollback bodies) fire once per
-  COMPILE, not per step — they show up in the trace as ``jax-trace``
-  category events and record tracing cost, which is itself a real
-  serving cost on first dispatch.
+  sync measures the host's wait on the device. Spans belong around host
+  call sites only: inside jit-traced code one would time JAX's tracing,
+  once per compile.
+- **Enabled spans also land in the profiler's trace.** Each enabled span
+  opens a ``jax.profiler.TraceAnnotation`` of the same name for its
+  duration, its int/float/str/bool args attached as event statistics
+  (list args stay in the tracer's own event). Under
+  ``jax.profiler.trace`` the program's spans then appear on the
+  ``/host:CPU`` plane of the ``.xplane.pb``, on one clock with the
+  device's ops. jax is imported on the enabled path only.
 - **Export is standard Chrome trace JSON** (``chrome://tracing`` /
   Perfetto): complete ``"X"`` events with microsecond ``ts``/``dur``,
   sorted by ``ts``, one pid per process and the Python thread id as
   ``tid``. ``validate()`` checks well-formedness (the CI trace-smoke
   gate): sorted timestamps, matched B/E nesting, non-negative X
   durations.
+
+The scheduler's spans (``serving/scheduler.py``), each tied to its
+requests by the args it carries:
+
+- ``sched.admit`` (``queued``, ``free``): one admission round, holding
+  per prefill group ``sched.prefill`` (``bucket`` the padded width,
+  ``batch``, ``uids``, ``lengths``, ``waited_ms`` = admission clock
+  minus arrival, per request);
+- ``sched.dispatch`` (``seq``, ``active``): one decode chunk enqueued;
+- ``sched.harvest`` (``seq``): the whole harvest, holding
+  ``sched.sync`` (the host's wait for the chunk's tokens), the token
+  loop with its ``on_token`` callbacks, and ``sched.evict`` (``slots``).
 
 Usage::
 
@@ -48,8 +63,8 @@ import sys
 import threading
 import time
 
-__all__ = ["Tracer", "get_tracer", "enable", "disable", "span", "instant",
-           "traced", "save", "validate", "validate_file"]
+__all__ = ["Tracer", "get_tracer", "enable", "disable", "enabled", "span",
+           "instant", "traced", "save", "validate", "validate_file", "NULL"]
 
 
 class _NullSpan:
@@ -64,10 +79,21 @@ class _NullSpan:
 
 
 _NULL = _NullSpan()
+_SCALARS = (int, float, str, bool)
+_Annotation = None      # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _annotation(name: str, args: dict):
+    global _Annotation
+    if _Annotation is None:
+        from jax.profiler import TraceAnnotation
+        _Annotation = TraceAnnotation
+    return _Annotation(name, **{k: v for k, v in args.items()
+                                if isinstance(v, _SCALARS)})
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer, name, cat, args):
         self._tracer = tracer
@@ -76,11 +102,14 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self._ann = _annotation(self.name, self.args)
+        self._ann.__enter__()
         self._t0 = self._tracer._now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = self._tracer._now_us()
+        self._ann.__exit__(*exc)
         ev = {"name": self.name, "cat": self.cat, "ph": "X",
               "ts": self._t0, "dur": t1 - self._t0,
               "pid": self._tracer.pid,
@@ -157,6 +186,15 @@ def enable(clear: bool = True):
 
 def disable():
     _TRACER.enabled = False
+
+
+def enabled() -> bool:
+    """Whether the global tracer records: call sites build costly args
+    (per-request lists) only when it does."""
+    return _TRACER.enabled
+
+
+NULL = _NULL            # what span() returns while the tracer is off
 
 
 def span(name: str, cat: str = "obs", **args):

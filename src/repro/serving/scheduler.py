@@ -394,6 +394,17 @@ class ContinuousBatchingEngine:
                 groups.setdefault(key, []).append(r)
         return list(groups.values()) + singles
 
+    def _prefill_span(self, group: list[QueuedRequest], width: int,
+                      lengths: list[int], now: float):
+        """The group's ``sched.prefill`` span; its per-request lists are
+        built only while the tracer records."""
+        if not obs_trace.enabled():
+            return obs_trace.NULL
+        return obs_trace.span(
+            "sched.prefill", bucket=width, batch=len(group),
+            uids=[r.uid for r in group], lengths=lengths,
+            waited_ms=[1e3 * (now - r.arrival) for r in group])
+
     def _prefill_join(self, group: list[QueuedRequest], now: float):
         k = len(group)
         lengths = [r.prompt_len for r in group]
@@ -401,31 +412,35 @@ class ContinuousBatchingEngine:
                    for r in group]
         slots = self.pool.alloc_many(k)
         assert len(slots) == k      # _admit popped at most free_count
-        if self._length_aware and self.bucket_prompts:
-            width = _bucket(max(lengths), self.max_len - 1)
-            padded = np.zeros((k, width), np.int32)
-            for i, r in enumerate(group):
-                padded[i, :r.prompt_len] = r.prompt[0]
-            lp, pre_cache = self._prefill(
-                self.params, jnp.asarray(padded), max_len=self.max_len,
-                extra=group[0].extra,
-                length=jnp.asarray(lengths, jnp.int32))
-        else:
-            lp, pre_cache = self._prefill(
-                self.params, jnp.asarray(group[0].prompt),
-                max_len=self.max_len, extra=group[0].extra)
-        slots_v = jnp.asarray(slots, jnp.int32)
+        bucketed = self._length_aware and self.bucket_prompts
+        width = (_bucket(max(lengths), self.max_len - 1) if bucketed
+                 else lengths[0])
         lengths_v = jnp.asarray(lengths, jnp.int32)
+        with self._prefill_span(group, width, lengths, now):
+            if bucketed:
+                padded = np.zeros((k, width), np.int32)
+                for i, r in enumerate(group):
+                    padded[i, :r.prompt_len] = r.prompt[0]
+                padded = jnp.asarray(padded)
+                lp, pre_cache = self._prefill(
+                    self.params, padded, max_len=self.max_len,
+                    extra=group[0].extra, length=lengths_v)
+            else:
+                lp, pre_cache = self._prefill(
+                    self.params, jnp.asarray(group[0].prompt),
+                    max_len=self.max_len, extra=group[0].extra)
+            if self.draft is not None:
+                if bucketed:
+                    _, pre_d = self._dprefill(
+                        self.draft.params, padded, max_len=self.max_len,
+                        length=lengths_v)
+                else:
+                    _, pre_d = self._dprefill(
+                        self.draft.params, jnp.asarray(group[0].prompt),
+                        max_len=self.max_len)
+        slots_v = jnp.asarray(slots, jnp.int32)
         budgets_v = jnp.asarray(budgets, jnp.int32)
         if self.draft is not None:
-            if self._length_aware and self.bucket_prompts:
-                _, pre_d = self._dprefill(
-                    self.draft.params, jnp.asarray(padded),
-                    max_len=self.max_len, length=lengths_v)
-            else:
-                _, pre_d = self._dprefill(
-                    self.draft.params, jnp.asarray(group[0].prompt),
-                    max_len=self.max_len)
             if self.probs is None:
                 self.probs = jnp.zeros((self.slots, lp.shape[-1]),
                                        jnp.float32)
@@ -493,46 +508,47 @@ class ContinuousBatchingEngine:
         if inflight is None:
             return []
         with obs_trace.span("sched.harvest", seq=inflight.seq):
-            toks_np = np.asarray(inflight.tokens)   # the one host sync
+            with obs_trace.span("sched.sync"):
+                toks_np = np.asarray(inflight.tokens)   # the one host sync
             if inflight.counters is not None:
                 # the chunk is host-materialized by the sync above; its
                 # counter snapshot reads out with no extra sync point
                 self._counters_host = obs_counters.harvest(
                     self._counter_names, inflight.counters)
-        events: list = []
-        evictions: list[int] = []
-        for slot, uid in enumerate(inflight.owners):
-            info = self._live.get(uid) if uid is not None else None
-            if info is None:        # idle, or finished before this sync
-                continue
-            fresh: list[int] = []
-            for t in toks_np[slot]:
+            events: list = []
+            evictions: list[int] = []
+            for slot, uid in enumerate(inflight.owners):
+                info = self._live.get(uid) if uid is not None else None
+                if info is None:    # idle, or finished before this sync
+                    continue
+                fresh: list[int] = []
+                for t in toks_np[slot]:
+                    if info.remaining <= 0:
+                        break
+                    t = int(t)
+                    fresh.append(t)
+                    info.remaining -= 1
+                    info.emitted += 1
+                    if self.sampling.stops and t == self.sampling.eos_id:
+                        info.remaining = 0
+                if fresh:
+                    out = self._collected[uid]
+                    first = not out
+                    out.extend(fresh)
+                    if self.on_token is not None:
+                        self.on_token(uid, fresh, first)
+                    events.append(TokenEvent(uid, fresh, first))
                 if info.remaining <= 0:
-                    break
-                t = int(t)
-                fresh.append(t)
-                info.remaining -= 1
-                info.emitted += 1
-                if self.sampling.stops and t == self.sampling.eos_id:
-                    info.remaining = 0
-            if fresh:
-                out = self._collected[uid]
-                first = not out
-                out.extend(fresh)
-                if self.on_token is not None:
-                    self.on_token(uid, fresh, first)
-                events.append(TokenEvent(uid, fresh, first))
-            if info.remaining <= 0:
-                events.append(self._finish(uid, "done"))
-            elif info.deadline is not None and now > info.deadline:
-                # past-deadline occupant: free the slot, freeze it on
-                # device so chunks dispatched from here on skip it
-                evictions.append(info.slot)
-                events.append(self._finish(uid, "expired"))
-        if evictions:
-            with obs_trace.span("sched.evict", slots=len(evictions)):
-                self.done = self._evict_fn(
-                    self.done, jnp.asarray(evictions, jnp.int32))
+                    events.append(self._finish(uid, "done"))
+                elif info.deadline is not None and now > info.deadline:
+                    # past-deadline occupant: free the slot, freeze it on
+                    # device so chunks dispatched from here on skip it
+                    evictions.append(info.slot)
+                    events.append(self._finish(uid, "expired"))
+            if evictions:
+                with obs_trace.span("sched.evict", slots=len(evictions)):
+                    self.done = self._evict_fn(
+                        self.done, jnp.asarray(evictions, jnp.int32))
         return events
 
     def _finish(self, uid: int, reason: str) -> Finished:

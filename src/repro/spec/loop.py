@@ -88,17 +88,12 @@ def spec_decode_loop(model, draft, params, dparams, cache, dstate, probs,
         nxt = sample_from_dist(r_nxt, probs, sampling)
         nxt = jnp.where(done, jnp.int32(sampling.pad_id), nxt)
 
-        # draft chain + one-dispatch target verify of [nxt, d_1..d_k].
-        # these spans run at jax-trace time (once per compile, not per
-        # round) — they chart staging cost, the first-dispatch tax
-        from ..obs import trace as obs_trace
-        with obs_trace.span("spec.propose", cat="jax-trace", k=k):
-            d_toks, q_dists, d_states = draft.propose(
-                dparams, dstate, nxt, pos, k, r_draft, dcfg)
+        # draft chain + one-dispatch target verify of [nxt, d_1..d_k]
+        d_toks, q_dists, d_states = draft.propose(
+            dparams, dstate, nxt, pos, k, r_draft, dcfg)
         block = jnp.concatenate([nxt[:, None], d_toks], axis=1)
-        with obs_trace.span("spec.verify", cat="jax-trace", k=k):
-            t_logits, cache, t_states = V.verify_chain(
-                model, params, cache, block, pos)
+        t_logits, cache, t_states = V.verify_chain(
+            model, params, cache, block, pos)
         p_dists = sample_dist(t_logits, sampling)
 
         if k == 0:
@@ -131,9 +126,8 @@ def spec_decode_loop(model, draft, params, dparams, cache, dstate, probs,
 
         # roll both models back to the per-row committed point
         pos2 = pos + m
-        with obs_trace.span("spec.rollback", cat="jax-trace"):
-            cache2 = V.rollback(model, cache, t_states, m)
-            dstate2 = draft.select(dstate, d_states, m)
+        cache2 = V.rollback(model, cache, t_states, m)
+        dstate2 = draft.select(dstate, d_states, m)
 
         # next round's pending distribution: the residual at the stop slot
         # when the commit ended exactly at the acceptance boundary, the

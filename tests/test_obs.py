@@ -127,6 +127,169 @@ def test_trace_validator_catches_malformed(tmp_path):
     assert T.main([str(tmp_path / "missing.json")]) != 0
 
 
+def _xplane_host_events(path) -> list:
+    """(name, start ns, dur ns, stats) of every ``/host:`` event of a
+    profiler trace directory's ``.xplane.pb``."""
+    import glob
+    from jax.profiler import ProfileData
+    files = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1, files
+    out = []
+    for plane in ProfileData.from_file(files[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(e.name, e.start_ns, e.duration_ns, dict(e.stats))
+                        for e in line.events]
+    return out
+
+
+def test_enabled_span_lands_in_the_profiler_trace(tmp_path):
+    """An enabled span opens a profiler annotation of its name: its
+    scalar args become event statistics, its list args stay in the
+    tracer's own event."""
+    T.enable()
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            with T.span("sched.prefill", bucket=16, batch=2, tag="b",
+                        uids=[3, 4]):
+                pass
+    finally:
+        T.disable()
+    evs = [e for e in _xplane_host_events(tmp_path)
+           if e[0] == "sched.prefill"]
+    assert len(evs) == 1
+    _, _, dur, stats = evs[0]
+    assert dur > 0
+    assert stats == {"bucket": 16, "batch": 2, "tag": "b"}
+    (ev,) = T.get_tracer().events
+    assert ev["args"] == {"bucket": 16, "batch": 2, "tag": "b",
+                          "uids": [3, 4]}
+    T.get_tracer().clear()
+
+
+def test_disabled_tracer_makes_no_annotation(monkeypatch):
+    made, annotation = [], T._annotation
+    monkeypatch.setattr(T, "_annotation",
+                        lambda *a: made.append(a) or annotation(*a))
+    T.disable()
+    sp = T.span("sched.harvest", seq=1)
+    assert sp is T.NULL and sp is T.span("other")
+    with sp:
+        pass
+    assert made == [] and T.get_tracer().events == []
+    assert not T.enabled()
+
+
+def _virtual_sched(traced: bool, on_token=None):
+    """A tiny scheduler on a virtual clock: 2 slots, 5 requests submitted
+    a second apart, then driven with the clock 0.25 s further per step.
+    Returns (results, tracer events, {uid: arrival}, [(clock at step,
+    events recorded in it)])."""
+    model = LSTMModel(CFG)
+    params = model.init(jax.random.key(0))
+    now = [0.0]
+    sched = ContinuousBatchingEngine(model, params, slots=2, max_len=32,
+                                     sampling=GREEDY, chunk=4,
+                                     clock=lambda: now[0],
+                                     on_token=on_token)
+    arrival = {}
+    for i, plen in enumerate([3, 5, 9, 2, 6]):
+        now[0] = float(i)
+        prompt = jax.random.randint(jax.random.fold_in(jax.random.key(1),
+                                                       i),
+                                    (1, plen), 0, CFG.vocab_size)
+        arrival[sched.submit(prompt, 6 + i)] = now[0]
+    if traced:
+        T.enable()
+    steps, results = [], {}
+    try:
+        while sched.busy:
+            now[0] += 0.25
+            seen = len(T.get_tracer().events)
+            for fin in sched.step():
+                results[fin.uid] = fin.tokens
+            steps.append((now[0], T.get_tracer().events[seen:]))
+    finally:
+        T.disable()
+    return results, list(T.get_tracer().events), arrival, steps
+
+
+def test_prefill_spans_carry_uids_and_queue_wait():
+    results, events, arrival, steps = _virtual_sched(True)
+    assert set(results) == set(arrival)
+    uids = []
+    for t_step, evs in steps:
+        for ev in evs:
+            if ev["name"] != "sched.prefill":
+                continue
+            a = ev["args"]
+            assert a["batch"] == len(a["uids"]) == len(a["lengths"]) \
+                == len(a["waited_ms"])
+            assert a["bucket"] >= max(a["lengths"])
+            for uid, waited in zip(a["uids"], a["waited_ms"]):
+                assert waited == pytest.approx(1e3 * (t_step - arrival[uid]))
+            uids += a["uids"]
+    # every admitted request appears in exactly one prefill span
+    assert sorted(uids) == sorted(arrival)
+    names = {e["name"] for e in events}
+    assert {"sched.admit", "sched.prefill", "sched.dispatch",
+            "sched.harvest", "sched.sync"} <= names
+
+
+def _inside(child, parent) -> bool:
+    return (parent["ts"] <= child["ts"] and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + 1e-6)
+
+
+def test_sync_and_prefill_nest_in_their_parents():
+    streamed = []       # tracer clock at each on_token callback
+
+    def on_token(uid, toks, first):
+        streamed.append(T.get_tracer()._now_us())
+
+    results, events, _, _ = _virtual_sched(True, on_token)
+    by = lambda name: [e for e in events if e["name"] == name]
+    harvests, admits = by("sched.harvest"), by("sched.admit")
+    assert by("sched.sync") and len(by("sched.sync")) == len(harvests)
+    for name, parents in (("sched.sync", harvests),
+                          ("sched.prefill", admits)):
+        for ev in by(name):
+            assert sum(_inside(ev, p) for p in parents) == 1, name
+    # the harvest covers its token loop: every callback falls inside one
+    assert streamed
+    for t in streamed:
+        assert sum(_inside({"ts": t, "dur": 0}, h) for h in harvests) == 1
+    assert T.validate(T.get_tracer().export()) == []
+    T.get_tracer().clear()
+
+
+def test_tracing_does_not_change_tokens():
+    off, _, _, _ = _virtual_sched(False)
+    on, _, _, _ = _virtual_sched(True)
+    T.get_tracer().clear()
+    assert off.keys() == on.keys()
+    for uid in off:
+        assert np.array_equal(off[uid], on[uid])
+
+
+def test_scheduler_spans_reach_the_host_plane(tmp_path):
+    """Under the profiler the scheduler's spans land on the host plane,
+    one event per tracer span, each harvest with its chunk's ``seq``."""
+    with jax.profiler.trace(str(tmp_path)):
+        _, events, _, _ = _virtual_sched(True)
+    T.get_tracer().clear()
+    evs = _xplane_host_events(tmp_path)
+    names = ("sched.admit", "sched.prefill", "sched.dispatch",
+             "sched.harvest", "sched.sync")
+    for name in names:
+        host = [e for e in evs if e[0] == name]
+        assert host and len(host) == sum(e["name"] == name for e in events)
+    seqs = [st["seq"] for n, _, _, st in evs if n == "sched.harvest"]
+    assert sorted(seqs) == [e["args"]["seq"] for e in events
+                            if e["name"] == "sched.dispatch"]
+
+
 # ---------------------------------------------------------------- metrics
 def test_metrics_registry_kinds_and_exports(tmp_path):
     reg = M.MetricsRegistry()

@@ -9,6 +9,9 @@ widths — ``lstm_ptb`` (X=H=1500) and ``lstm_timit`` (X=153, H=1024), B=8
 at the serve defaults Spar_x=0.75 / Spar_h=0.5 — for one chip of a
 ``v5e:2x2`` topology that is described, not attached, and assert that
 each compiled program holds the Mosaic kernel (``tpu_custom_call``).
+Each kernel also names its op itself: compiled under a jitted wrapper of
+another name, the custom call still carries the kernel's own public
+name, so a profile finds the kernel whatever function wraps it.
 
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and the test workers import every
@@ -16,6 +19,7 @@ test file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -150,3 +154,32 @@ def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, arch, B, kind):
                for s, d in operands]
     compiled = jax.jit(fn).lower(*structs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _custom_call_names(hlo: str) -> list[str]:
+    """The instruction name of each custom call in compiled HLO text."""
+    return re.findall(r"(?m)^\s*(?:ROOT )?%(\S+) = .*custom-call\(", hlo)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_kernel_names_its_op_under_any_wrapper(one_chip, no_compile_cache,
+                                               kind):
+    """The kernel, unwrapped from its own jit and compiled inside a jitted
+    function of another name, gives its op the kernel's public name (the
+    fused step's op is ``fused_brds_lstm_step``)."""
+    fn, operands = _args(_shapes("lstm_timit"), kind, 8)
+    raw = functools.partial(fn.func.__wrapped__, **fn.keywords)
+
+    def some_other_wrapper(*a):
+        return raw(*a)
+
+    structs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+               for s, d in operands]
+    hlo = jax.jit(some_other_wrapper).lower(*structs).compile().as_text()
+    ops = _custom_call_names(hlo)
+    assert ops, "no custom call in the compiled program"
+    name = fn.func.__name__
+    if kind == "fused_step":
+        assert name == "fused_brds_lstm_step"
+    assert any(re.fullmatch(rf"{name}(\.\d+)?", op) for op in ops), ops
+    assert not any(op.startswith("some_other_wrapper") for op in ops), ops
