@@ -40,12 +40,12 @@ from .rb_spmv import (DEF_BLOCK_ROWS, acc_scratch, dual_gate, dual_scratch,
 
 
 def _delta_rb_spmv_kernel(d_ref, f_ref, vals_ref, deltas_ref, out_ref,
-                          cols_scr, vals_scr, acc_scr, *, K):
+                          fam_scr, acc_scr, *, K):
     """Grid step: one block of rows. d/f (B, Xp); vals/deltas (bR, Kp);
     out_ref (B, bR)."""
     dm = d_ref[...].astype(jnp.float32) * f_ref[...]               # (B, Xp)
-    acc = gather_dot(dm, vals_ref, deltas_ref, cols_scr, vals_scr, acc_scr,
-                     K=K, acc_dtype=jnp.float32)
+    acc = gather_dot(dm, vals_ref, deltas_ref, fam_scr, acc_scr, K=K,
+                     acc_dtype=jnp.float32)
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
@@ -70,7 +70,7 @@ def delta_rb_spmv(values, deltas, d, fired, *,
                   rows_spec(block_rows, K)],
         out_specs=pl.BlockSpec((B, block_rows), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((B, R), d.dtype),
-        scratch_shapes=[*family_scratch(block_rows, K, jnp.float32),
+        scratch_shapes=[family_scratch(B, block_rows, K, X, jnp.float32),
                         acc_scratch(B, block_rows, jnp.float32)],
         interpret=interpret,
         name="delta_rb_spmv",
@@ -114,7 +114,7 @@ def delta_rb_dual_spmv(vals_x, deltas_x, dx, fx, vals_h, deltas_h, dh, fh,
                   pl.BlockSpec((B, block_rows), lambda i: (0, i))],
         out_specs=pl.BlockSpec((B, block_rows), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((B, R), m.dtype),
-        scratch_shapes=dual_scratch(B, block_rows, Kx, Kh),
+        scratch_shapes=dual_scratch(B, block_rows, X, Kx, H, Kh),
         interpret=interpret,
         name="delta_rb_dual_spmv",
     )(dx, fx, dh, fh, vals_x, deltas_x, vals_h, deltas_h, m)

@@ -155,7 +155,7 @@ def fused_brds_lstm_step(vals_x, deltas_x, x, vals_h, deltas_h, h, bias,
         out_shape=[jax.ShapeDtypeStruct((B, H), c_prev.dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((B, R), x.dtype),
                         pltpu.VMEM((2, B, H), jnp.float32),
-                        *dual_scratch(B, block_rows, Kx, Kh)],
+                        *dual_scratch(B, block_rows, X, Kx, H, Kh)],
         interpret=interpret,
         name="fused_brds_lstm_step",
     )(_lut(), x, h, c_prev, vals_x, deltas_x, vals_h, deltas_h,
@@ -223,7 +223,7 @@ def fused_brds_delta_lstm_step(vals_x, deltas_x, dx, fx, vals_h, deltas_h,
                    jax.ShapeDtypeStruct((B, R), m.dtype)],
         scratch_shapes=[pltpu.VMEM((B, R), jnp.float32),
                         pltpu.VMEM((2, B, H), jnp.float32),
-                        *dual_scratch(B, block_rows, Kx, Kh)],
+                        *dual_scratch(B, block_rows, X, Kx, H, Kh)],
         interpret=interpret,
         name="fused_brds_delta_lstm_step",
     )(_lut(), dx, fx, dh, fh, c_prev, vals_x, deltas_x, vals_h, deltas_h,
@@ -294,7 +294,8 @@ def fused_brds_lstm_step_q8(vals_x, deltas_x, scales_x, qx, vals_h, deltas_h,
         scratch_shapes=[pltpu.VMEM((B, R), jnp.float32),
                         pltpu.VMEM((B, R), jnp.float32),
                         pltpu.VMEM((2, B, H), jnp.float32),
-                        *dual_scratch(B, block_rows, Kx, Kh, jnp.int32)],
+                        *dual_scratch(B, block_rows, X, Kx, H, Kh,
+                                      jnp.int32)],
         interpret=interpret,
         name="fused_brds_lstm_step_q8",
     )(_lut(), qx, qh, c_prev, vals_x, deltas_x, scales_x.reshape(1, R),
@@ -360,7 +361,8 @@ def fused_brds_delta_lstm_step_q8(vals_x, deltas_x, scales_x, qdx, vals_h,
         scratch_shapes=[pltpu.VMEM((B, R), jnp.float32),
                         pltpu.VMEM((B, R), jnp.float32),
                         pltpu.VMEM((2, B, H), jnp.float32),
-                        *dual_scratch(B, block_rows, Kx, Kh, jnp.int32)],
+                        *dual_scratch(B, block_rows, X, Kx, H, Kh,
+                                      jnp.int32)],
         interpret=interpret,
         name="fused_brds_delta_lstm_step_q8",
     )(_lut(), qdx, qdh, c_prev, vals_x, deltas_x, scales_x.reshape(1, R),
@@ -450,7 +452,7 @@ def fused_brds_lstm_scan(vals_x, deltas_x, xs, vals_h, deltas_h, h0, bias,
                         pltpu.VMEM((B, lane_pad(H)), h0.dtype),
                         pltpu.VMEM((B, H), c0.dtype),
                         pltpu.VMEM((2, B, H), jnp.float32),
-                        *dual_scratch(B, block_rows, Kx, Kh)],
+                        *dual_scratch(B, block_rows, X, Kx, H, Kh)],
         interpret=interpret,
         name="fused_brds_lstm_scan",
     )(_lut(), xs, h0, c0, vals_x, deltas_x, vals_h, deltas_h,
@@ -564,7 +566,7 @@ def fused_brds_delta_lstm_scan(vals_x, deltas_x, xs, vals_h, deltas_h, h0,
                         pltpu.VMEM((B, Hp), jnp.float32),
                         pltpu.VMEM((B, R), jnp.float32),
                         pltpu.VMEM((2, B, H), jnp.float32),
-                        *dual_scratch(B, block_rows, Kx, Kh)],
+                        *dual_scratch(B, block_rows, X, Kx, H, Kh)],
         interpret=interpret,
         name="fused_brds_delta_lstm_scan",
     )(_lut(), xs, h0, c0, x_ref0, h_ref0, m0, vals_x, deltas_x, vals_h,

@@ -38,11 +38,11 @@ from .rb_spmv import (DEF_BLOCK_ROWS, acc_scratch, dual_gate, dual_scratch,
 
 
 def _rb_spmv_q8_kernel(qx_ref, vals_ref, deltas_ref, scales_ref, out_ref,
-                       cols_scr, vals_scr, acc_scr, *, K):
+                       fam_scr, acc_scr, *, K):
     """Grid step: one block of rows. qx (B, Xp) int codes; vals/deltas
     (bR, Kp); scales (1, bR) combined row·act dequant; out (B, bR) f32."""
-    acc = gather_dot(qx_ref[...], vals_ref, deltas_ref, cols_scr, vals_scr,
-                     acc_scr, K=K, acc_dtype=jnp.int32)
+    acc = gather_dot(qx_ref[...], vals_ref, deltas_ref, fam_scr, acc_scr,
+                     K=K, acc_dtype=jnp.int32)
     out_ref[...] = acc.astype(jnp.float32) * scales_ref[...][0][None, :]
 
 
@@ -68,7 +68,7 @@ def rb_spmv_q8(values, deltas, scales, qx, *,
                   pl.BlockSpec((1, block_rows), lambda i: (0, i))],
         out_specs=pl.BlockSpec((B, block_rows), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((B, R), jnp.float32),
-        scratch_shapes=[*family_scratch(block_rows, K, jnp.int32),
+        scratch_shapes=[family_scratch(B, block_rows, K, X, jnp.int32),
                         acc_scratch(B, block_rows, jnp.int32)],
         interpret=interpret,
         name="rb_spmv_q8",
@@ -132,7 +132,8 @@ def rb_dual_parts_q8(vals_x, deltas_x, scales_x, qx, vals_h, deltas_h,
                   rows_spec(block_rows, Kh), rows_spec(block_rows, Kh), sblk],
         out_specs=[oblk, oblk],
         out_shape=[jax.ShapeDtypeStruct((B, R), jnp.float32)] * 2,
-        scratch_shapes=dual_scratch(B, block_rows, Kx, Kh, jnp.int32),
+        scratch_shapes=dual_scratch(B, block_rows, X, Kx, H, Kh,
+                                    jnp.int32),
         interpret=interpret,
         name="rb_dual_parts_q8",
     )(qx, qh, vals_x, deltas_x, scales_x.reshape(1, R), vals_h, deltas_h,

@@ -90,7 +90,8 @@ class ServeEngine:
         to ``with_quant(plan)``, and packing emits RowBalancedSparseQ8 so
         decode runs the int32-accumulate q8 kernels.
         Returns (params, report) — report is None when the engine is
-        dense."""
+        dense; a packed report carries ``gather_visit_share`` per packed
+        leaf (``SparsityPlan.gather_visit_shares``)."""
         if self.sparsity is None:
             return params, None
         plan = (self.sparsity.compile(params)
@@ -132,6 +133,8 @@ class ServeEngine:
             pack = getattr(self.model, "supports_packed_decode", False)
         if pack:
             packed, pack_report = plan.pack(pruned, masks)
+            pack_report["gather_visit_share"] = plan.gather_visit_shares(
+                packed)
             packed = self._maybe_partition(packed)
             if not self._dist and hasattr(self.model, "pad_packed_params"):
                 # hoist the kernel-block row padding out of the per-token

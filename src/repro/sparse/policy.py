@@ -392,6 +392,17 @@ class SparsityPlan:
                             packed_bytes=packed_bytes,
                             ratio=packed_bytes / max(dense_bytes, 1))
 
+    def gather_visit_shares(self, packed) -> dict:
+        """{path: share of source chunks the packed gather visits} for each
+        row-balanced leaf of a concrete ``pack`` output — a property of the
+        packed weights, computed on the host once
+        (``kernels.rb_spmv.gather_visit_share``)."""
+        from ..kernels.rb_spmv import gather_visit_share
+        flat = jax.tree_util.tree_flatten_with_path(
+            packed, is_leaf=lambda v: hasattr(v, "deltas"))[0]
+        return {_path_str(path): gather_visit_share(leaf.deltas, leaf.ncols)
+                for path, leaf in flat if hasattr(leaf, "deltas")}
+
     # -- kernel dispatch -------------------------------------------------
     def matvec(self, path: str, packed, x):
         """Dispatch one packed matvec through the site's format with the

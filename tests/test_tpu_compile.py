@@ -5,7 +5,9 @@ Pallas compiler (Mosaic), so it cannot catch a primitive Mosaic has no
 lowering for, a gather across vregs, a misaligned block or a VMEM
 overflow. These tests compile each decode kernel at the paper's published
 widths — ``lstm_ptb`` (X=H=1500) and ``lstm_timit`` (X=153, H=1024), B=8
-(and B=1, 4 at ``lstm_ptb``),
+(and B=1, 4 at ``lstm_ptb``; the fused step also at the served batches,
+B=32 and B=64, where the gather's dynamic source-chunk loop runs four and
+eight batch groups),
 at the serve defaults Spar_x=0.75 / Spar_h=0.5 — for one chip of a
 ``v5e:2x2`` topology that is described, not attached, and assert that
 each compiled program holds the Mosaic kernel (``tpu_custom_call``).
@@ -154,6 +156,13 @@ def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, arch, B, kind):
                for s, d in operands]
     compiled = jax.jit(fn).lower(*structs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("arch,B", [("lstm_ptb", 32), ("lstm_timit", 64)])
+def test_fused_step_compiles_at_served_batch(one_chip, no_compile_cache,
+                                             arch, B):
+    test_kernel_compiles_for_v5e(one_chip, no_compile_cache, arch, B,
+                                 "fused_step")
 
 
 def _custom_call_names(hlo: str) -> list[str]:
