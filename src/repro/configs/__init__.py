@@ -12,6 +12,7 @@ from . import (
     minitron_8b,
     llama3_2_3b,
     rwkv6_7b,
+    granite_4_0_h_micro,
 )
 
 ALL = [
@@ -25,6 +26,7 @@ ALL = [
     minitron_8b.CONFIG,
     llama3_2_3b.CONFIG,
     rwkv6_7b.CONFIG,
+    granite_4_0_h_micro.CONFIG,
 ]
 
 ARCH_NAMES = [c.name for c in ALL]
@@ -51,6 +53,9 @@ def smoke_config(name: str) -> ArchConfig:
         window=32 if full.window else None,
         d_rnn=128 if full.d_rnn else 0,
         rwkv_chunk=16,
+        mamba_heads=4 if full.mamba_heads else 0,
+        mamba_head_dim=32 if full.mamba_heads else 0,
+        mamba_d_state=16 if full.mamba_heads else 0,
         grad_accum=1,
         block_q=64,
         block_kv=64,

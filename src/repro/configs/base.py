@@ -35,8 +35,18 @@ class ArchConfig:
     activation: str = "silu_glu"      # silu_glu | gelu_glu | gelu | sq_relu
     qk_norm: bool = False
     rope_theta: float = 500000.0
-    # block pattern, repeated over depth: attn | attn_local | rec | rwkv
+    norm_eps: float | None = None     # None: the norm's own default
+    # block pattern, repeated over depth: attn | attn_local | rec | rwkv |
+    # mamba
     block_pattern: tuple = ("attn",)
+    use_rope: bool = True             # False: NoPE attention
+    attention_multiplier: float = 0.0  # softmax scale; 0 → 1/sqrt(head_dim)
+    # Granite's scalars: embeddings × m, each residual branch × m, and the
+    # logits divided by ``logits_scaling``; a tied head reads the table
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    tie_embeddings: bool = False
     window: int | None = None         # local attention window
     # MoE
     moe: bool = False
@@ -60,6 +70,12 @@ class ArchConfig:
     d_rnn: int = 0                    # defaults to d_model
     conv_width: int = 4
     rwkv_chunk: int = 128
+    # Mamba-2 mixer: heads × head_dim = d_inner, state width, B/C groups
+    # (the causal conv is ``conv_width`` wide, with a bias)
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_d_state: int = 0
+    mamba_groups: int = 1
     # capabilities
     subquadratic: bool = False        # can run long_500k
     # parallelism layout: 'tp' (model axis = tensor/expert parallel) or
@@ -87,6 +103,13 @@ class ArchConfig:
     @property
     def rnn_width(self) -> int:
         return self.d_rnn or self.d_model
+
+    @property
+    def precision(self):
+        """Matmul precision of a float32 model: full (HIGHEST), as the
+        float32 reference computes; the backend's default otherwise."""
+        import jax
+        return jax.lax.Precision.HIGHEST if self.dtype == "float32" else None
 
 
 @dataclasses.dataclass(frozen=True)

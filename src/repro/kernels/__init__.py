@@ -22,5 +22,8 @@ from .ops import (
     flash_attention,
     decode_attention,
     interpret_mode,
+    model_backend,
+    rb_project,
+    mamba2_ssm_step,
 )
 from . import ref

@@ -32,6 +32,8 @@ from .rb_spmv_q8 import (rb_spmv_q8 as _rb_spmv_q8_kernel,
 from .lstm_gates import lstm_gates as _lstm_gates_kernel
 from .flash_attention import flash_attention as _flash_kernel
 from .decode_attention import decode_attention as _decode_kernel
+from .mamba2_ssm import mamba2_ssm_step as _mamba2_ssm_kernel
+from .rb_spmv import lane_pad
 from ..core.packing import RowBalancedSparse, block_rows_for
 from ..quant.scheme import quantize as _quantize
 from ..sparse import backend as _backend
@@ -41,6 +43,13 @@ def interpret_mode() -> bool:
     """Pallas interpret mode iff the default backend is not a TPU: the TPU
     always runs the compiled kernels."""
     return jax.default_backend() != "tpu"
+
+
+def model_backend() -> str | None:
+    """The backend a model's own kernels take: the configured default
+    where the Pallas kernels compile (a TPU), the ``ref`` twins where they
+    would only be interpreted (the CPU runs)."""
+    return "ref" if interpret_mode() else None
 
 
 def _resolve(backend: str | None, use_kernel: bool | None) -> str:
@@ -109,6 +118,47 @@ def rb_spmv(s: RowBalancedSparse, x: jnp.ndarray, *, block_rows: int = 256,
     y = _rb_spmv_kernel(vals, deltas, x, block_rows=eff,
                         interpret=interpret_mode())
     return y[:, :R] if Rp > R else y
+
+
+def project_block_rows(K: int) -> int:
+    """Kernel row block of a packed projection with K kept columns per
+    row: 256, or 128 from K = 2048 up, where a 256-row block's columns,
+    values and gathered chunks no longer fit the kernel's VMEM."""
+    return 128 if lane_pad(K) >= 2048 else 256
+
+
+def project_rows(ncols: int) -> int:
+    """Activation rows per packed-projection kernel call: 32 up to 4096
+    source columns, 8 beyond, where the replicated source chunks of more
+    rows overflow the kernel's VMEM."""
+    return 32 if lane_pad(ncols) <= 4096 else 8
+
+
+def rb_project(x, s: RowBalancedSparse, *, backend: str | None = None):
+    """x (..., ncols) through packed weights → (..., rows): every
+    projection of a packed model. The leading axes (batch, or batch ×
+    positions at prefill) are flattened into activation rows and run
+    through ``rb_spmv`` — the Pallas ``gather_dot`` on a TPU — in calls of
+    at most ``project_rows`` rows (a ``lax.map`` over row tiles), at the
+    row block ``project_block_rows`` that ``pad_packed`` gave the struct.
+    ``backend=None`` is ``model_backend()``: the ref twin on the CPU."""
+    lead, X = x.shape[:-1], x.shape[-1]
+    x2 = x.reshape(-1, X)
+    if backend is None:
+        backend = model_backend()
+    if _resolve(backend, None) == "ref":
+        y = _ref.rb_spmv_ref(s, x2)
+    else:
+        n, t = x2.shape[0], project_rows(s.ncols)
+        call = functools.partial(rb_spmv, s,
+                                 block_rows=project_block_rows(s.K),
+                                 backend=backend)
+        if n <= t:
+            y = call(x2)
+        else:
+            xs = jnp.pad(x2, ((0, (-n) % t), (0, 0))).reshape(-1, t, X)
+            y = jax.lax.map(call, xs).reshape(-1, s.rows)[:n]
+    return y.reshape(*lead, s.rows)
 
 
 def rb_dual_spmv(sx: RowBalancedSparse, x, sh: RowBalancedSparse, h, bias,
@@ -545,3 +595,17 @@ def decode_attention(q, k, v, lengths, *, block_kv: int = 512,
     bk = max(g for g in (block_kv, 256, 128, 64, 32, 16, 8, 1) if S % g == 0)
     return _decode_kernel(q, k, v, lengths, block_kv=bk,
                           interpret=interpret_mode())
+
+
+# ------------------------------------------------------------------ mamba-2
+
+def mamba2_ssm_step(state, x, dt, A, Bm, Cm, D, *,
+                    backend: str | None = None):
+    """One Mamba-2 decode step over every slot and head: S ← exp(dt A) S
+    + (dt x) ⊗ B, y = S C + D x. state (B, H, P, N) float32, updated in
+    place by the kernel; x (B, H, P); dt (B, H); A, D (H,); Bm, Cm (B, G,
+    N). Returns (new state, y (B, H, P))."""
+    if _resolve(backend, None) == "ref":
+        return _ref.mamba2_ssm_step_ref(state, x, dt, A, Bm, Cm, D)
+    return _mamba2_ssm_kernel(state, x, dt, A, Bm, Cm, D,
+                              interpret=interpret_mode())

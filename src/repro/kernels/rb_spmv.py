@@ -20,8 +20,9 @@ vreg, so x is split into 128-lane chunks, each chunk is gathered by
 ``col % 128`` and kept where ``col // 128`` names it. Per 8-row tile and
 128-lane K-chunk only a window of ``window_chunks`` chunks from the tile's
 own smallest ``col // 128`` is visited where every K-chunk of the tile
-fits it (ascending columns keep the window narrow), and every chunk where
-one does not. Each lane's chunk is visited and exactly one chunk selects
+fits it (ascending columns keep the window narrow), and where one does
+not, each K-chunk's own chunks from its smallest ``col // 128`` to its
+largest. Each lane's chunk is visited and exactly one chunk selects
 the lane, so every lane gathers the value a visit of all chunks would,
 and the result is bitwise that visit's. The gathered tile is
 multiplied by the values and accumulated per 128-lane K-chunk, then
@@ -90,13 +91,13 @@ def family_scratch(B: int, block_rows: int, K: int, X: int, acc_dtype):
     receives it as a tuple of refs): columns + values, and — where the
     window (``window_chunks``) is narrower than the source — the batch
     group's replicated source chunks, indexed by a dynamic chunk, and each
-    tile's window starts and fit flag, reduced in VMEM and copied to SMEM
-    (with the copy's semaphore)."""
+    tile's first and last chunk per K-chunk and fit flag, reduced in VMEM
+    and copied to SMEM (with the copy's semaphore)."""
     Kp, nx = lane_pad(K), lane_pad(X) // LANES
     scr = [pltpu.VMEM((block_rows, Kp), jnp.int32),
            pltpu.VMEM((block_rows, Kp), acc_dtype)]
     if window_chunks(nx, K) < nx:
-        spans = (block_rows // SUBLANES, lane_pad(Kp // LANES + 1))
+        spans = (block_rows // SUBLANES, lane_pad(2 * (Kp // LANES) + 1))
         scr += [pltpu.VMEM((nx, min(B, BATCH_GROUP), SUBLANES, LANES),
                            jnp.float32),
                 pltpu.VMEM(spans, jnp.int32), pltpu.SMEM(spans, jnp.int32),
@@ -111,7 +112,8 @@ def acc_scratch(B: int, block_rows: int, acc_dtype):
 
 def _windows(cols_scr, spans_v, spans, sem, U):
     """Per 8-row tile: column kc of ``spans`` the smallest ``col >> 7`` in
-    K-chunk kc, column nkc 1 where every K-chunk's chunks fit ``U``.
+    K-chunk kc, column nkc 1 where every K-chunk's chunks fit ``U``,
+    column nkc + 1 + kc the largest ``col >> 7`` in K-chunk kc.
     Reduced on vectors (in f32, exact for chunk numbers), then one copy to
     SMEM, where the tile loop reads them as scalars."""
     bR, Kp = cols_scr.shape
@@ -126,6 +128,7 @@ def _windows(cols_scr, spans_v, spans, sem, U):
         last = jnp.max(jnp.max(hi, axis=1), axis=1, keepdims=True)
         first, last = first.astype(jnp.int32), last.astype(jnp.int32)
         out = jnp.where(lane == kc, first, out)
+        out = jnp.where(lane == nkc + 1 + kc, last, out)
         widest = jnp.maximum(widest, last - first + 1)
     spans_v[...] = jnp.where(lane == nkc, (widest <= U).astype(jnp.int32),
                              out)
@@ -162,16 +165,19 @@ def gather_dot(src, vals_ref, deltas_ref, fam_scr, acc_scr, *, K: int,
     ``col >> 7`` names. Per 8-row tile and 128-lane K-chunk the chunk
     visits start at the tile's own smallest ``col >> 7`` and run
     ``U = window_chunks(nx, K)`` chunks (clamped to the last), where every
-    K-chunk of the tile fits U; a tile that does not fit, and a family
-    with U = nx, visit all ``nx`` chunks. Every lane's chunk is visited,
+    K-chunk of the tile fits U; a tile that does not fit visits each
+    K-chunk's chunks from its smallest ``col >> 7`` to its largest, and a
+    family with U = nx all ``nx`` chunks. Every lane's chunk is visited,
     exactly one chunk selects each lane (a clamped repeat gathers the same
     value again), so each lane gathers what a visit of all chunks would
     and the products and sums that follow are unchanged: the result is
     bitwise the full visit's, for any column order. A window's visits are
     unrolled: a loop with a dynamic trip count per K-chunk would split the
     tile's gathers into blocks the compiler cannot overlap. A tile that
-    does not fit loops over all ``nx`` chunks, one visit per iteration:
-    ascending columns seldom take that path, so its code is kept small.
+    does not fit loops over each K-chunk's own chunks, one visit per
+    iteration: ascending columns take that path where rows are long
+    against the window's slack (the 8192-wide MLP down projection of a
+    hybrid LM), and its code is kept small.
 
     The per-row arithmetic — chunked lane products summed over K-chunks,
     then one lane reduction — depends on neither the block's row count nor
@@ -248,9 +254,10 @@ def gather_dot(src, vals_ref, deltas_ref, fam_scr, acc_scr, *, K: int,
                         gs = window(0, gs)
                     elif fits:
                         gs = window(spans[t, kc], gs)
-                    else:   # every chunk, one at a time
+                    else:   # the K-chunk's own chunks, one at a time
                         gs = lax.fori_loop(
-                            0, nx, lambda c, gs: window(c, gs, 1), gs)
+                            spans[t, kc], spans[t, nkc + 1 + kc] + 1,
+                            lambda c, gs: window(c, gs, 1), gs)
                     if acc_dtype != jnp.float32:
                         gs = [lax.convert_element_type(g, acc_dtype)
                               for g in gs]
@@ -276,8 +283,9 @@ def gather_visit_share(deltas, ncols: int) -> float:
     """Share of (8-row tile, 128-lane K-chunk, source chunk) triples that
     ``gather_dot`` visits for a packed family, out of the ``nx`` chunks per
     (tile, K-chunk) of a visit of every chunk: ``window_chunks`` per
-    K-chunk of a tile whose K-chunks all fit that window, ``nx`` for any
-    other tile. A property of the packed weights, computed on the host from
+    K-chunk of a tile whose K-chunks all fit that window, and for any
+    other tile each K-chunk's chunks from its first to its last. A
+    property of the packed weights, computed on the host from
     ``deltas`` ((..., R, K), leading axes stacked layers) and the logical
     column count ``ncols``. Rows pad to a tile multiple with zero rows
     (column 0), as in the kernel; lanes past K repeat the row's last
@@ -298,8 +306,9 @@ def gather_visit_share(deltas, ncols: int) -> float:
     starts = np.arange(0, K, LANES)
     last = np.maximum.reduceat(hi.max(axis=2), starts, axis=-1)
     first = np.minimum.reduceat(hi.min(axis=2), starts, axis=-1)
-    fits = (last - first + 1).max(axis=-1) <= U      # (L, tiles)
-    return float(np.where(fits, U, nx).mean() / nx)
+    width = last - first + 1                          # (L, tiles, K-chunks)
+    fits = width.max(axis=-1, keepdims=True) <= U
+    return float(np.where(fits, U, width).mean() / nx)
 
 
 def src_spec(B: int, X: int, index_map=lambda *_: (0, 0)):

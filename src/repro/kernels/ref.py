@@ -218,3 +218,25 @@ def decode_attention_ref(q, k, v, lengths) -> jnp.ndarray:
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhk,bhkd->bhd", p, vf).astype(q.dtype)
+
+
+# ------------------------------------------------------------------ mamba-2
+
+def mamba2_ssm_step_ref(state, x, dt, A, Bm, Cm, D):
+    """One Mamba-2 decode step. state (B, H, P, N); x (B, H, P); dt (B, H)
+    (after softplus); A (H,) (negative); Bm, Cm (B, G, N), head h reading
+    group h // (H/G); D (H,). Returns (new state, y (B, H, P)), float32:
+
+        S ← exp(dt A) S + (dt x) ⊗ B;   y = S C + D x
+    """
+    f32 = jnp.float32
+    H, G = state.shape[1], Bm.shape[1]
+    Bh = jnp.repeat(Bm.astype(f32), H // G, axis=1)[:, :, None, :]
+    Ch = jnp.repeat(Cm.astype(f32), H // G, axis=1)[:, :, None, :]
+    dt = dt.astype(f32)
+    x = x.astype(f32)
+    decay = jnp.exp(dt * A.astype(f32)[None, :])
+    s = (decay[..., None, None] * state.astype(f32)
+         + (dt[..., None] * x)[..., None] * Bh)
+    y = jnp.sum(s * Ch, axis=-1) + D.astype(f32)[None, :, None] * x
+    return s, y

@@ -1,4 +1,5 @@
-"""Attention: GQA/MQA/MHA with qk-norm, RoPE, local windows, KV caches.
+"""Attention: GQA/MQA/MHA with qk-norm, RoPE or NoPE, local windows, KV
+caches.
 
 Tensor-parallel strategy (model axis = 16 on the production mesh):
 - Projection WEIGHTS shard greedily: heads → model if divisible, else
@@ -26,7 +27,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from .layers import PSpec, rope, rmsnorm, pmm
+from .layers import PSpec, rope, rmsnorm, pmm, project
+from ..core.packing import RowBalancedSparse
 from ..sharding import constrain, _current_mesh
 
 
@@ -57,12 +59,22 @@ def model_axis_size() -> int:
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
 
 
+def _heads(x, w, head_dim: int):
+    """x (B, S, d) @ a (d, H, Dh) weight → (B, S, H, Dh); a packed weight
+    (rows = H·Dh) goes through ``layers.project``."""
+    if isinstance(w, RowBalancedSparse):
+        y = project(x, w)
+        return y.reshape(*y.shape[:-1], -1, head_dim)
+    return pmm(x, w)
+
+
 def qkv_project(p: dict, x, positions, *, qk_norm: bool, rope_theta: float,
-                use_rope: bool = True):
-    """x (B, S, d) → q (B, S, Hq, Dh), k/v (B, S, Hkv, Dh)."""
-    q = pmm(x, p["wq"])
-    k = pmm(x, p["wk"])
-    v = pmm(x, p["wv"])
+                use_rope: bool = True, head_dim: int | None = None):
+    """x (B, S, d) → q (B, S, Hq, Dh), k/v (B, S, Hkv, Dh). ``head_dim``
+    shapes the heads of packed weights."""
+    q = _heads(x, p["wq"], head_dim)
+    k = _heads(x, p["wk"], head_dim)
+    v = _heads(x, p["wv"], head_dim)
     if qk_norm:
         q = rmsnorm(q, p["q_norm"])
         k = rmsnorm(k, p["k_norm"])
@@ -102,6 +114,8 @@ def prepare_heads(q, k, v, true_heads: int):
 
 def out_project(p: dict, o):
     wo = p["wo"]
+    if isinstance(wo, RowBalancedSparse):
+        return project(o.reshape(*o.shape[:2], -1), wo)
     return pmm(o.reshape(*o.shape[:2], -1),
                wo.reshape(-1, wo.shape[-1]))
 
@@ -110,17 +124,19 @@ def out_project(p: dict, o):
 
 def blocked_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                       q_offset: int = 0, block_q: int = 512,
-                      block_kv: int = 1024):
+                      block_kv: int = 1024, scale: float | None = None,
+                      precision=None):
     """Online-softmax attention, lax.scan over q and kv blocks. MHA layout:
     q, k, v (B, S, H, D) with equal head counts (see prepare_heads).
-    q_offset: absolute position of q[0] (kv positions are 0..Sk-1)."""
+    q_offset: absolute position of q[0] (kv positions are 0..Sk-1).
+    ``scale`` None is 1/sqrt(D); ``precision`` that of the two einsums."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     bq = min(block_q, Sq)
     bk = min(block_kv, Sk)
     assert Sq % bq == 0 and Sk % bk == 0, (Sq, Sk, bq, bk)
     nq, nk = Sq // bq, Sk // bk
-    scale = jnp.float32(D ** -0.5)
+    scale = jnp.float32(D ** -0.5 if scale is None else scale)
 
     qb = q.reshape(B, nq, bq, H, D).transpose(1, 0, 2, 3, 4)
     kb = k.reshape(B, nk, bk, H, D).transpose(1, 0, 2, 3, 4)
@@ -135,7 +151,8 @@ def blocked_attention(q, k, v, *, causal: bool = True, window: int | None = None
             m, l, acc = carry
             ik, kblk, vblk = kx
             s = jnp.einsum("bqhd,bkhd->bhqk", qf,
-                           kblk.astype(jnp.float32))        # (B,H,bq,bk)
+                           kblk.astype(jnp.float32),
+                           precision=precision)             # (B,H,bq,bk)
             qpos = q_offset + iq * bq + jnp.arange(bq)
             kpos = ik * bk + jnp.arange(bk)
             mask = jnp.ones((bq, bk), bool)
@@ -149,7 +166,8 @@ def blocked_attention(q, k, v, *, causal: bool = True, window: int | None = None
             alpha = jnp.exp(m - m_new)
             l_new = alpha * l + jnp.sum(p, axis=-1)
             acc_new = acc * alpha[..., None] + jnp.einsum(
-                "bhqk,bkhd->bhqd", p, vblk.astype(jnp.float32))
+                "bhqk,bkhd->bhqd", p, vblk.astype(jnp.float32),
+                precision=precision)
             return (m_new, l_new, acc_new), None
 
         m0 = jnp.full((B, H, bq), NEG, jnp.float32)
@@ -164,12 +182,15 @@ def blocked_attention(q, k, v, *, causal: bool = True, window: int | None = None
     return outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, D)
 
 
-def full_attention(q, k, v, *, causal=True, window=None, q_offset=0):
-    """Direct einsum attention (small seq). MHA layout (B, S, H, D)."""
+def full_attention(q, k, v, *, causal=True, window=None, q_offset=0,
+                   scale=None, precision=None):
+    """Direct einsum attention (small seq). MHA layout (B, S, H, D).
+    ``scale`` None is 1/sqrt(D)."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * D ** -0.5,
-                   k.astype(jnp.float32))
+    scale = D ** -0.5 if scale is None else scale
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
+                   k.astype(jnp.float32), precision=precision)
     qpos = q_offset + jnp.arange(Sq)
     kpos = jnp.arange(Sk)
     mask = jnp.ones((Sq, Sk), bool)
@@ -179,18 +200,22 @@ def full_attention(q, k, v, *, causal=True, window=None, q_offset=0):
         mask &= kpos[None, :] > qpos[:, None] - window
     s = jnp.where(mask[None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+    o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32),
+                   precision=precision)
     return o.astype(q.dtype)
 
 
-def decode_attention_einsum(q, k_cache, v_cache, length, window=None):
+def decode_attention_einsum(q, k_cache, v_cache, length, window=None,
+                            scale=None, precision=None):
     """q: (B, 1, H, D) (post prepare_heads); caches (B, Smax, H, D);
     length: scalar valid length, or (B,) per-sequence lengths (continuous
-    batching over ragged sequences). Returns (B, 1, H, D)."""
+    batching over ragged sequences). ``scale`` None is 1/sqrt(D).
+    Returns (B, 1, H, D)."""
     B, _, H, D = q.shape
     Smax = k_cache.shape[1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * D ** -0.5,
-                   k_cache.astype(jnp.float32))
+    scale = D ** -0.5 if scale is None else scale
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
+                   k_cache.astype(jnp.float32), precision=precision)
     if getattr(length, "ndim", 0) == 1:
         length = length.reshape(-1, 1, 1, 1)
     kpos = jnp.arange(Smax)[None, None, None, :]
@@ -199,7 +224,8 @@ def decode_attention_einsum(q, k_cache, v_cache, length, window=None):
         mask = mask & (kpos > length - 1 - window)
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v_cache.astype(jnp.float32))
+    o = jnp.einsum("bhqk,bkhd->bqhd", p, v_cache.astype(jnp.float32),
+                   precision=precision)
     return o.astype(q.dtype)
 
 

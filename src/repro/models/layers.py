@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.packing import RowBalancedSparse
+from ..kernels.ops import rb_project
 from ..sharding import Axes, constrain
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32, "float16": jnp.float16}
@@ -109,10 +111,12 @@ def norm_defs(kind: str, dim: int) -> dict:
             "b": PSpec((dim,), ("embed",), init="zeros", dtype=jnp.float32)}
 
 
-def apply_norm(kind: str, p: dict, x):
+def apply_norm(kind: str, p: dict, x, eps: float | None = None):
+    """The block's norm; ``eps`` None is each norm's own default."""
+    kw = {} if eps is None else {"eps": eps}
     if kind == "rmsnorm":
-        return rmsnorm(x, p["w"])
-    return layernorm(x, p["w"], p["b"])
+        return rmsnorm(x, p["w"], **kw)
+    return layernorm(x, p["w"], p["b"], **kw)
 
 
 # ------------------------------------------------------------------ RoPE
@@ -169,6 +173,15 @@ def _pmm_bwd(res, g):
 pmm.defvjp(_pmm_fwd, _pmm_bwd)
 
 
+def project(x, w):
+    """x @ w over the last dim of x: ``pmm`` for a dense (K, *N) weight,
+    ``kernels.ops.rb_project`` for a packed one (rows = flattened N),
+    which returns (..., rows)."""
+    if isinstance(w, RowBalancedSparse):
+        return rb_project(x, w)
+    return pmm(x, w)
+
+
 # ------------------------------------------------------------------ MLP
 
 def mlp_defs(d_model: int, d_ff: int, activation: str, dtype) -> dict:
@@ -200,13 +213,13 @@ def _act(activation: str, x):
 def mlp_apply(p: dict, x, activation: str):
     """x: (..., d_model). Weight masks (BRDS) are pre-applied to params."""
     if activation.endswith("_glu"):
-        g = _act(activation, pmm(x, p["w_gate"]))
-        u = pmm(x, p["w_up"])
+        g = _act(activation, project(x, p["w_gate"]))
+        u = project(x, p["w_up"])
         h = g * u
     else:
-        h = _act(activation, pmm(x, p["w_up"]))
+        h = _act(activation, project(x, p["w_up"]))
     h = constrain(h, "batch", "seq", "mlp") if h.ndim == 3 else h
-    return pmm(h, p["w_down"])
+    return project(h, p["w_down"])
 
 
 # ------------------------------------------------------------------ embed

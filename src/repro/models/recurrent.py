@@ -1,9 +1,12 @@
-"""Recurrent sequence mixers: RG-LRU (RecurrentGemma/Griffin) and RWKV6.
+"""Recurrent sequence mixers: RG-LRU (RecurrentGemma/Griffin), RWKV6 and
+Mamba-2 (Granite 4.0-H).
 
-Both are the paper's W_h analogue made modern — data-dependent diagonal /
-low-rank recurrences. Training/prefill use parallel forms (associative scan
-for RG-LRU; chunked linear attention for RWKV6); decode uses O(1) state
-updates. These blocks make the `long_500k` shape runnable (sub-quadratic).
+All are the paper's W_h analogue made modern — data-dependent diagonal /
+low-rank / selective state-space recurrences. Training/prefill use
+parallel forms (associative scan for RG-LRU; chunked linear attention for
+RWKV6) or, for Mamba-2, a sequential scan of the decode step's state
+update; decode uses O(1) state updates. These blocks make the `long_500k`
+shape runnable (sub-quadratic).
 """
 from __future__ import annotations
 
@@ -15,6 +18,7 @@ import jax.numpy as jnp
 
 from .layers import PSpec, _act
 from ..core.packing import RowBalancedSparse
+from ..kernels import ops as kops
 from ..sharding import constrain
 
 
@@ -22,19 +26,14 @@ def _proj(x, w):
     """y = x @ W for dense (d_in, *out) weights OR BRDS-packed weights
     (RowBalancedSparse with rows = flattened out dim, cols = d_in).
 
-    Packed path = the paper's accelerator datapath on the decode hot loop:
-    only (rows, K) values + narrow delta indices stream from HBM; the
-    column gather is the rb_spmv kernel's semantics (kernels/rb_spmv.py is
-    the TPU implementation; this is its lowering-friendly ref form).
-    Returns (B, S, F) with F = prod(out dims)."""
-    B, S, d = x.shape
+    Packed weights go through ``kernels.ops.rb_project``, the one packed
+    projection every model family shares: on a TPU the Pallas gather
+    streams only the (rows, K) values and narrow delta indices, with no
+    (B·S, rows, K) gather materialised. Returns (B, S, F) with
+    F = prod(out dims)."""
     if isinstance(w, RowBalancedSparse):
-        cols = jnp.cumsum(w.deltas.astype(jnp.int32), axis=1)   # (R, K)
-        g = jnp.take(x.reshape(B * S, d), cols, axis=1)         # (BS, R, K)
-        y = jnp.einsum("brk,rk->br", g.astype(jnp.float32),
-                       w.values.astype(jnp.float32))
-        y = constrain(y, None, "mlp")    # rows stay model-sharded
-        return y.reshape(B, S, w.rows).astype(x.dtype)
+        y = constrain(kops.rb_project(x, w), None, None, "mlp")
+        return y.astype(x.dtype)
     return jnp.einsum("bsd,df->bsf", x, w.reshape(w.shape[0], -1))
 
 # ================================================================= RG-LRU
@@ -303,3 +302,137 @@ def rwkv_state_defs(batch: int, num_heads: int, head_dim: int, d_model: int,
         "x_cm": PSpec((batch, d_model), ("batch", "embed"), init="zeros",
                       dtype=dtype),
     }
+
+
+# ================================================================ Mamba-2
+
+def mamba2_defs(d_model: int, heads: int, head_dim: int, d_state: int,
+                groups: int, conv_width: int, dtype) -> dict:
+    """Mamba-2 mixer (the SSD layer of Dao & Gu 2024, as HF's
+    ``GraniteMoeHybridMambaLayer``): in_proj to [z; xBC; dt], a causal
+    depthwise conv with bias over xBC, per-head dt bias, A (as log -A) and
+    skip D, a gated RMSNorm over d_inner, out_proj."""
+    d_inner = heads * head_dim
+    conv_dim = d_inner + 2 * groups * d_state
+    return {
+        "w_inproj": PSpec((d_model, d_inner + conv_dim + heads),
+                          ("embed", "mlp"), dtype=dtype),
+        "conv_w": PSpec((conv_width, conv_dim), ("conv", "mlp"),
+                        dtype=dtype, scale=0.3),
+        "conv_b": PSpec((conv_dim,), ("mlp",), init="zeros", dtype=dtype),
+        "dt_bias": PSpec((heads,), ("heads",), init="zeros",
+                         dtype=jnp.float32),
+        "A_log": PSpec((heads,), ("heads",), init="zeros",
+                       dtype=jnp.float32),
+        "D": PSpec((heads,), ("heads",), init="ones", dtype=jnp.float32),
+        "norm": PSpec((d_inner,), ("mlp",), init="zeros",
+                      dtype=jnp.float32),
+        "w_outproj": PSpec((d_inner, d_model), ("mlp", "embed"),
+                           dtype=dtype),
+    }
+
+
+def mamba2_state_defs(batch: int, heads: int, head_dim: int, d_state: int,
+                      groups: int, conv_width: int) -> dict:
+    """Per-slot state: the conv's last ``conv_width - 1`` xBC inputs and
+    the SSM state, both float32."""
+    conv_dim = heads * head_dim + 2 * groups * d_state
+    return {
+        "conv": PSpec((batch, conv_width - 1, conv_dim),
+                      ("batch", "conv", "mlp"), init="zeros",
+                      dtype=jnp.float32),
+        "ssm": PSpec((batch, heads, head_dim, d_state),
+                     ("batch", "heads", "head_dim", None), init="zeros",
+                     dtype=jnp.float32),
+    }
+
+
+def _mamba_in(p, x):
+    """in_proj and its split → (z, xBC before the conv, dt after softplus
+    (..., H), A (H,))."""
+    H = p["A_log"].shape[-1]
+    d_inner = p["norm"].shape[-1]
+    zxbcdt = _proj(x, p["w_inproj"])
+    z = zxbcdt[..., :d_inner]
+    xbc = zxbcdt[..., d_inner:-H]
+    dt = jax.nn.softplus(zxbcdt[..., -H:].astype(jnp.float32)
+                         + p["dt_bias"].astype(jnp.float32))
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    return z, xbc, dt, A
+
+
+def _mamba_split(p, xbc, groups: int):
+    """Conv output (..., conv_dim) → x (..., H, P), B and C (..., G, N)."""
+    H, d_inner = p["A_log"].shape[-1], p["norm"].shape[-1]
+    lead = xbc.shape[:-1]
+    N = (xbc.shape[-1] - d_inner) // (2 * groups)
+    x = xbc[..., :d_inner].reshape(*lead, H, d_inner // H)
+    Bm = xbc[..., d_inner:d_inner + groups * N].reshape(*lead, groups, N)
+    Cm = xbc[..., d_inner + groups * N:].reshape(*lead, groups, N)
+    return x, Bm, Cm
+
+
+def _mamba_out(p, y, z, eps: float):
+    """Gated RMSNorm over d_inner (one group) and out_proj:
+    out_proj(rmsnorm(y · silu(z)) · w)."""
+    yf = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    var = jnp.mean(yf * yf, axis=-1, keepdims=True)
+    yf = yf * jax.lax.rsqrt(var + eps) * (1.0 + p["norm"].astype(jnp.float32))
+    return _proj(yf.astype(z.dtype), p["w_outproj"])
+
+
+def mamba2_step(p: dict, x, state, *, groups: int, eps: float):
+    """Single-token decode. x (B, 1, d_model); state {'conv', 'ssm'}.
+    The state update is ``kernels.ops.mamba2_ssm_step`` (the Pallas kernel
+    on a TPU). Returns (y (B, 1, d_model), new state)."""
+    B = x.shape[0]
+    z, xbc, dt, A = _mamba_in(p, x)
+    xbc, conv = _causal_conv(xbc, p["conv_w"], p["conv_b"], state["conv"])
+    xs, Bm, Cm = _mamba_split(p, jax.nn.silu(xbc[:, 0]), groups)
+    ssm, y = kops.mamba2_ssm_step(state["ssm"], xs, dt[:, 0], A, Bm, Cm,
+                                  p["D"], backend=kops.model_backend())
+    y = y.reshape(B, 1, -1).astype(x.dtype)
+    return _mamba_out(p, y, z, eps), {"conv": conv.astype(jnp.float32),
+                                      "ssm": ssm}
+
+
+def mamba2_apply(p: dict, x, *, groups: int, eps: float, length=None,
+                 backend: str | None = None):
+    """Whole sequence from zero state. x (B, S, d_model). The projections
+    and the conv run once over all positions; the recurrence is a
+    sequential scan of the decode step's state update (exact). ``length``
+    ((B,) or scalar true lengths) masks padding: past it dt is 0, so the
+    SSM state stays put, and the conv state holds the last
+    ``conv_width - 1`` inputs before it. ``backend`` picks the state
+    update's implementation (``ref`` in training: the kernel has no VJP).
+    Returns (y (B, S, d_model), state)."""
+    Bsz, S = x.shape[:2]
+    z, xbc_in, dt, A = _mamba_in(p, x)
+    xbc, _ = _causal_conv(xbc_in, p["conv_w"], p["conv_b"])
+    xs, Bm, Cm = _mamba_split(p, jax.nn.silu(xbc), groups)
+    W = p["conv_w"].shape[0]
+    xp = jnp.concatenate(
+        [jnp.zeros((Bsz, W - 1, xbc_in.shape[-1]), xbc_in.dtype), xbc_in], 1)
+    if length is None:
+        conv = xp[:, S:]
+    else:
+        length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (Bsz,))
+        dt = jnp.where(jnp.arange(S)[None, :, None] < length[:, None, None],
+                       dt, 0.0)
+        idx = length[:, None] + jnp.arange(W - 1)[None, :]
+        conv = jnp.take_along_axis(xp, idx[..., None], axis=1)
+    if backend is None:
+        backend = kops.model_backend()
+    H, P = xs.shape[-2:]
+    s0 = jnp.zeros((Bsz, H, P, Bm.shape[-1]), jnp.float32)
+
+    def step(s, inp):
+        x_t, dt_t, b_t, c_t = inp
+        return kops.mamba2_ssm_step(s, x_t, dt_t, A, b_t, c_t, p["D"],
+                                    backend=backend)
+
+    ssm, ys = jax.lax.scan(step, s0, tuple(
+        jnp.swapaxes(a, 0, 1) for a in (xs, dt, Bm, Cm)))
+    y = jnp.swapaxes(ys, 0, 1).reshape(Bsz, S, -1).astype(x.dtype)
+    return _mamba_out(p, y, z, eps), {"conv": conv.astype(jnp.float32),
+                                      "ssm": ssm}

@@ -479,10 +479,13 @@ def lstm_policy(spar_x: float, spar_h: float, *, backend: str = "auto",
 
 
 # (pattern, family, layout) — family A pruned at spar_a, B at spar_b.
+# Rows are output units: q/k/v (d, heads, head_dim) fan in over d alone.
 _TRANSFORMER_FAMILIES = (
     (r"(mlp|moe)/w_(gate|up|down)$", "a", "in_out"),
     (r"rwkv/w_cm[12]$", "a", "in_out"),
-    (r"(attn|xattn)/w[qkvo]$", "b", "in_out"),
+    (r"(attn|xattn)/w[qkv]$", "b", "out_trailing"),
+    (r"(attn|xattn)/wo$", "b", "in_out"),
+    (r"mamba/w_(inproj|outproj)$", "b", "in_out"),
     (r"rec/(w_in_gelu|w_in_rec|w_gate_a|w_gate_x|w_out)$", "b", "in_out"),
     (r"rwkv/w_[rkvgw]$", "b", "out_trailing"),
     (r"rwkv/w_out$", "b", "in_out"),

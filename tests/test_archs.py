@@ -93,6 +93,7 @@ def test_full_config_matches_assignment(name):
         "minitron-8b": (32, 4096, 32, 8, 16384, 256000),
         "llama3.2-3b": (28, 3072, 24, 8, 8192, 128256),
         "rwkv6-7b": (32, 4096, 64, 64, 14336, 65536),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }
     cfg = get_arch(name)
     L, d, H, kv, ff, V = spec[name]

@@ -2,8 +2,8 @@
 
 Per 8-row tile and 128-lane K-chunk, ``gather_dot`` visits the
 ``window_chunks`` source chunks from the tile's smallest ``col >> 7``
-where every K-chunk of the tile fits that window, and all chunks where
-one does not. These cases build column layouts by hand — every K-chunk of
+where every K-chunk of the tile fits that window, and where one does not,
+each K-chunk's chunks from its smallest ``col >> 7`` to its largest. These cases build column layouts by hand — every K-chunk of
 a tile in one source chunk, uniform sorted columns (the benchmark's
 weights), tiles that mix narrow and spanning rows, columns in random
 order, windows that end at the last chunk, block-padding rows — and check
@@ -229,13 +229,14 @@ def test_visit_share_of_spanning_tiles_or_narrow_sources_is_one(X, layout):
 
 def test_visit_share_counts_padding_rows_and_stacked_layers():
     """A partial last tile pads with zero rows (column 0, chunk 0), so a
-    tile whose rows lie in chunks 6–8 then spans chunks 0–8 and visits all
-    12; stacked layers average over all their tiles."""
+    tile whose K-chunks lie in chunks 6, 7 and 8 no longer fits the window
+    and visits chunks 0–6, 0–7 and 0–8: 24 of 36; stacked layers average
+    over all their tiles."""
     rng = np.random.default_rng(3)
     row = _chunked_row(rng, 1500, 375, [6, 7, 8])
     ones = np.ones((8, 375), np.float32)
     s4 = _packed(np.tile(row, (4, 1)), 1500, ones[:4])
-    assert gather_visit_share(s4.deltas, 1500) == 1.0
+    assert gather_visit_share(s4.deltas, 1500) == 24 / 36
     s8 = _packed(np.tile(row, (8, 1)), 1500, ones)
     assert gather_visit_share(s8.deltas, 1500) == 0.5
     spanning = _packed(np.tile(rng.choice(1500, 375, replace=False),

@@ -192,3 +192,41 @@ def test_kernel_names_its_op_under_any_wrapper(one_chip, no_compile_cache,
         assert name == "fused_brds_lstm_step"
     assert any(re.fullmatch(rf"{name}(\.\d+)?", op) for op in ops), ops
     assert not any(op.startswith("some_other_wrapper") for op in ops), ops
+
+
+# The granite_h_decode cell's kernels at its published widths: each packed
+# projection (rows, K, source columns) at the rows per call and the row
+# block ``kernels.ops.rb_project`` runs it at, and the Mamba-2 state step
+# at the cell's 32 slots.
+HYBRID = {"mamba_in": (8512, 1024, 2048), "mamba_out": (2048, 2048, 4096),
+          "mlp_up": (8192, 512, 2048), "mlp_down": (2048, 2048, 8192),
+          "attn_q": (2048, 1024, 2048), "attn_kv": (512, 1024, 2048)}
+
+
+@pytest.mark.parametrize("proj", sorted(HYBRID))
+def test_hybrid_projection_compiles_for_v5e(one_chip, no_compile_cache,
+                                            proj):
+    from repro.kernels import ops
+    from repro.kernels.rb_spmv import rb_spmv
+    R, K, X = HYBRID[proj]
+    block = block_rows_for(R, ops.project_block_rows(K))
+    Rp = R + (-R) % block
+    fn = functools.partial(rb_spmv, block_rows=block, interpret=False)
+    structs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+               (((Rp, K), jnp.float32), ((Rp, K), jnp.int16),
+                ((ops.project_rows(X), X), jnp.float32))]
+    compiled = jax.jit(fn).lower(*structs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mamba2_ssm_step_compiles_for_v5e(one_chip, no_compile_cache):
+    from repro.kernels.mamba2_ssm import mamba2_ssm_step
+    B, H, P, N, G = 32, 64, 64, 128, 1
+    f32 = jnp.float32
+    structs = [jax.ShapeDtypeStruct(s, f32, sharding=one_chip) for s in
+               ((B, H, P, N), (B, H, P), (B, H), (H,), (B, G, N), (B, G, N),
+                (H,))]
+    fn = functools.partial(mamba2_ssm_step, interpret=False)
+    hlo = jax.jit(fn, donate_argnums=0).lower(*structs).compile().as_text()
+    ops = _custom_call_names(hlo)
+    assert any(re.fullmatch(r"mamba2_ssm_step(\.\d+)?", op) for op in ops), ops
