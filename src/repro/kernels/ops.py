@@ -34,6 +34,7 @@ from .flash_attention import flash_attention as _flash_kernel
 from .decode_attention import decode_attention as _decode_kernel
 from .mamba2_ssm import mamba2_ssm_step as _mamba2_ssm_kernel
 from .rb_spmv import lane_pad
+from .. import hw as _hw
 from ..core.packing import RowBalancedSparse, block_rows_for
 from ..quant.scheme import quantize as _quantize
 from ..sparse import backend as _backend
@@ -132,6 +133,34 @@ def project_rows(ncols: int) -> int:
     source columns, 8 beyond, where the replicated source chunks of more
     rows overflow the kernel's VMEM."""
     return 32 if lane_pad(ncols) <= 4096 else 8
+
+
+# Device time of the packed gather per kept entry per activation row, by
+# device kind: the fused LSTM step at B=32 in ``ptb_decode`` (2.57 ms a
+# call over 6.75 M kept entries × 32 rows; PERF_LEDGER.jsonl, PR 14), the
+# kernel's best rate on the chip. A kind with no rate serves packed.
+GATHER_S_PER_ENTRY_ROW = {"TPU v5 lite": 1.2e-11}
+
+
+def device_kind() -> str:
+    """``device_kind`` of the default backend's first device."""
+    return jax.devices()[0].device_kind
+
+
+def serve_dense(rows: int, ncols: int, K: int, n: int, kind: str,
+                itemsize: int = 4) -> bool:
+    """Whether a packed projection of ``rows`` × ``ncols`` keeping ``K``
+    columns a row serves faster as its pruned dense matrix at ``n``
+    activation rows on a ``kind`` device: true where the gather's work,
+    ``n · rows · K`` entry-rows at ``GATHER_S_PER_ENTRY_ROW[kind]``, takes
+    at least as long as streaming the dense bytes at the chip's HBM rate
+    (``hw.PEAKS``). The gather's work grows with ``n``, the dense bytes do
+    not. A kind with no measured rate keeps the leaf packed."""
+    g = GATHER_S_PER_ENTRY_ROW.get(kind)
+    if g is None:
+        return False
+    return (n * rows * K * g
+            >= rows * ncols * itemsize / _hw.peaks(kind).hbm_bytes_per_s)
 
 
 def rb_project(x, s: RowBalancedSparse, *, backend: str | None = None):

@@ -59,22 +59,25 @@ def model_axis_size() -> int:
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
 
 
-def _heads(x, w, head_dim: int):
-    """x (B, S, d) @ a (d, H, Dh) weight → (B, S, H, Dh); a packed weight
-    (rows = H·Dh) goes through ``layers.project``."""
+def _heads(x, w, head_dim: int, precision=None):
+    """x (B, S, d) @ a (d, H, Dh) weight → (B, S, H, Dh), a dense one at
+    ``precision``; a packed weight (rows = H·Dh) goes through
+    ``layers.project``."""
     if isinstance(w, RowBalancedSparse):
         y = project(x, w)
         return y.reshape(*y.shape[:-1], -1, head_dim)
-    return pmm(x, w)
+    return pmm(x, w, precision)
 
 
 def qkv_project(p: dict, x, positions, *, qk_norm: bool, rope_theta: float,
-                use_rope: bool = True, head_dim: int | None = None):
+                use_rope: bool = True, head_dim: int | None = None,
+                precision=None):
     """x (B, S, d) → q (B, S, Hq, Dh), k/v (B, S, Hkv, Dh). ``head_dim``
-    shapes the heads of packed weights."""
-    q = _heads(x, p["wq"], head_dim)
-    k = _heads(x, p["wk"], head_dim)
-    v = _heads(x, p["wv"], head_dim)
+    shapes the heads of packed weights; ``precision`` is that of dense
+    ones."""
+    q = _heads(x, p["wq"], head_dim, precision)
+    k = _heads(x, p["wk"], head_dim, precision)
+    v = _heads(x, p["wv"], head_dim, precision)
     if qk_norm:
         q = rmsnorm(q, p["q_norm"])
         k = rmsnorm(k, p["k_norm"])
@@ -112,12 +115,12 @@ def prepare_heads(q, k, v, true_heads: int):
     return q, k, v
 
 
-def out_project(p: dict, o):
+def out_project(p: dict, o, precision=None):
     wo = p["wo"]
     if isinstance(wo, RowBalancedSparse):
         return project(o.reshape(*o.shape[:2], -1), wo)
     return pmm(o.reshape(*o.shape[:2], -1),
-               wo.reshape(-1, wo.shape[-1]))
+               wo.reshape(-1, wo.shape[-1]), precision)
 
 
 # ------------------------------------------------------- blocked attention

@@ -8,6 +8,7 @@ NamedShardings (via repro.sharding).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -137,8 +138,8 @@ def rope(x, positions, theta: float = 10000.0):
 
 # ------------------------------------------------------- TP-aware matmul
 
-@jax.custom_vjp
-def pmm(x, w):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def pmm(x, w, precision=None):
     """y = x @ w over the last dim of x; w (K, N) or (K, *N) flattened.
 
     Same forward as einsum; the custom VJP keeps the ACTIVATION gradient in
@@ -146,24 +147,26 @@ def pmm(x, w):
     preferred_element_type=f32, which makes XLA all-reduce f32 partials when
     the contracted dim is model-sharded — 2× the wire bytes of the tensor-
     parallel backward (nemotron §Perf cell 2). Weight grads stay f32.
+    ``precision`` is the dots' (None: the backend's default).
     """
     wf = w.reshape(w.shape[0], -1)
-    y = x @ wf
+    y = jnp.matmul(x, wf, precision=precision)
     return y.reshape(*x.shape[:-1], *w.shape[1:])
 
 
-def _pmm_fwd(x, w):
-    return pmm(x, w), (x, w)
+def _pmm_fwd(x, w, precision):
+    return pmm(x, w, precision), (x, w)
 
 
-def _pmm_bwd(res, g):
+def _pmm_bwd(precision, res, g):
     x, w = res
     wf = w.reshape(w.shape[0], -1)
     g2 = g.reshape(*x.shape[:-1], wf.shape[1]).astype(x.dtype)
-    gx = g2 @ wf.T                                   # bf16-wire activation grad
+    # bf16-wire activation grad
+    gx = jnp.matmul(g2, wf.T, precision=precision)
     gw = jax.lax.dot_general(
         x.reshape(-1, x.shape[-1]), g2.reshape(-1, g2.shape[-1]),
-        (((0,), (0,)), ((), ())),
+        (((0,), (0,)), ((), ())), precision=precision,
         preferred_element_type=jnp.float32)          # f32 accumulation
     # cotangent dtype must match the primal's; the f32 accumulation above
     # still protects the local reduction, the DP all-reduce rides in bf16
@@ -173,13 +176,13 @@ def _pmm_bwd(res, g):
 pmm.defvjp(_pmm_fwd, _pmm_bwd)
 
 
-def project(x, w):
-    """x @ w over the last dim of x: ``pmm`` for a dense (K, *N) weight,
-    ``kernels.ops.rb_project`` for a packed one (rows = flattened N),
-    which returns (..., rows)."""
+def project(x, w, precision=None):
+    """x @ w over the last dim of x: ``pmm`` at ``precision`` for a dense
+    (K, *N) weight, ``kernels.ops.rb_project`` for a packed one (rows =
+    flattened N), which returns (..., rows)."""
     if isinstance(w, RowBalancedSparse):
         return rb_project(x, w)
-    return pmm(x, w)
+    return pmm(x, w, precision)
 
 
 # ------------------------------------------------------------------ MLP
@@ -210,16 +213,17 @@ def _act(activation: str, x):
     raise ValueError(activation)
 
 
-def mlp_apply(p: dict, x, activation: str):
-    """x: (..., d_model). Weight masks (BRDS) are pre-applied to params."""
+def mlp_apply(p: dict, x, activation: str, precision=None):
+    """x: (..., d_model). Weight masks (BRDS) are pre-applied to params;
+    ``precision`` is that of the dense projections."""
     if activation.endswith("_glu"):
-        g = _act(activation, project(x, p["w_gate"]))
-        u = project(x, p["w_up"])
+        g = _act(activation, project(x, p["w_gate"], precision))
+        u = project(x, p["w_up"], precision)
         h = g * u
     else:
-        h = _act(activation, project(x, p["w_up"]))
+        h = _act(activation, project(x, p["w_up"], precision))
     h = constrain(h, "batch", "seq", "mlp") if h.ndim == 3 else h
-    return project(h, p["w_down"])
+    return project(h, p["w_down"], precision)
 
 
 # ------------------------------------------------------------------ embed

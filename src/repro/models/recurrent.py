@@ -22,9 +22,10 @@ from ..kernels import ops as kops
 from ..sharding import constrain
 
 
-def _proj(x, w):
-    """y = x @ W for dense (d_in, *out) weights OR BRDS-packed weights
-    (RowBalancedSparse with rows = flattened out dim, cols = d_in).
+def _proj(x, w, precision=None):
+    """y = x @ W for dense (d_in, *out) weights, at ``precision``, OR
+    BRDS-packed weights (RowBalancedSparse with rows = flattened out dim,
+    cols = d_in).
 
     Packed weights go through ``kernels.ops.rb_project``, the one packed
     projection every model family shares: on a TPU the Pallas gather
@@ -34,7 +35,8 @@ def _proj(x, w):
     if isinstance(w, RowBalancedSparse):
         y = constrain(kops.rb_project(x, w), None, None, "mlp")
         return y.astype(x.dtype)
-    return jnp.einsum("bsd,df->bsf", x, w.reshape(w.shape[0], -1))
+    return jnp.einsum("bsd,df->bsf", x, w.reshape(w.shape[0], -1),
+                      precision=precision)
 
 # ================================================================= RG-LRU
 
@@ -347,12 +349,12 @@ def mamba2_state_defs(batch: int, heads: int, head_dim: int, d_state: int,
     }
 
 
-def _mamba_in(p, x):
+def _mamba_in(p, x, precision):
     """in_proj and its split → (z, xBC before the conv, dt after softplus
     (..., H), A (H,))."""
     H = p["A_log"].shape[-1]
     d_inner = p["norm"].shape[-1]
-    zxbcdt = _proj(x, p["w_inproj"])
+    zxbcdt = _proj(x, p["w_inproj"], precision)
     z = zxbcdt[..., :d_inner]
     xbc = zxbcdt[..., d_inner:-H]
     dt = jax.nn.softplus(zxbcdt[..., -H:].astype(jnp.float32)
@@ -372,42 +374,45 @@ def _mamba_split(p, xbc, groups: int):
     return x, Bm, Cm
 
 
-def _mamba_out(p, y, z, eps: float):
+def _mamba_out(p, y, z, eps: float, precision):
     """Gated RMSNorm over d_inner (one group) and out_proj:
     out_proj(rmsnorm(y · silu(z)) · w)."""
     yf = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
     var = jnp.mean(yf * yf, axis=-1, keepdims=True)
     yf = yf * jax.lax.rsqrt(var + eps) * (1.0 + p["norm"].astype(jnp.float32))
-    return _proj(yf.astype(z.dtype), p["w_outproj"])
+    return _proj(yf.astype(z.dtype), p["w_outproj"], precision)
 
 
-def mamba2_step(p: dict, x, state, *, groups: int, eps: float):
+def mamba2_step(p: dict, x, state, *, groups: int, eps: float,
+                precision=None):
     """Single-token decode. x (B, 1, d_model); state {'conv', 'ssm'}.
     The state update is ``kernels.ops.mamba2_ssm_step`` (the Pallas kernel
-    on a TPU). Returns (y (B, 1, d_model), new state)."""
+    on a TPU); ``precision`` is that of dense projections. Returns
+    (y (B, 1, d_model), new state)."""
     B = x.shape[0]
-    z, xbc, dt, A = _mamba_in(p, x)
+    z, xbc, dt, A = _mamba_in(p, x, precision)
     xbc, conv = _causal_conv(xbc, p["conv_w"], p["conv_b"], state["conv"])
     xs, Bm, Cm = _mamba_split(p, jax.nn.silu(xbc[:, 0]), groups)
     ssm, y = kops.mamba2_ssm_step(state["ssm"], xs, dt[:, 0], A, Bm, Cm,
                                   p["D"], backend=kops.model_backend())
     y = y.reshape(B, 1, -1).astype(x.dtype)
-    return _mamba_out(p, y, z, eps), {"conv": conv.astype(jnp.float32),
-                                      "ssm": ssm}
+    return _mamba_out(p, y, z, eps, precision), {
+        "conv": conv.astype(jnp.float32), "ssm": ssm}
 
 
 def mamba2_apply(p: dict, x, *, groups: int, eps: float, length=None,
-                 backend: str | None = None):
+                 backend: str | None = None, precision=None):
     """Whole sequence from zero state. x (B, S, d_model). The projections
     and the conv run once over all positions; the recurrence is a
     sequential scan of the decode step's state update (exact). ``length``
     ((B,) or scalar true lengths) masks padding: past it dt is 0, so the
     SSM state stays put, and the conv state holds the last
     ``conv_width - 1`` inputs before it. ``backend`` picks the state
-    update's implementation (``ref`` in training: the kernel has no VJP).
-    Returns (y (B, S, d_model), state)."""
+    update's implementation (``ref`` in training: the kernel has no VJP),
+    ``precision`` that of dense projections. Returns (y (B, S, d_model),
+    state)."""
     Bsz, S = x.shape[:2]
-    z, xbc_in, dt, A = _mamba_in(p, x)
+    z, xbc_in, dt, A = _mamba_in(p, x, precision)
     xbc, _ = _causal_conv(xbc_in, p["conv_w"], p["conv_b"])
     xs, Bm, Cm = _mamba_split(p, jax.nn.silu(xbc), groups)
     W = p["conv_w"].shape[0]
@@ -434,5 +439,5 @@ def mamba2_apply(p: dict, x, *, groups: int, eps: float, length=None,
     ssm, ys = jax.lax.scan(step, s0, tuple(
         jnp.swapaxes(a, 0, 1) for a in (xs, dt, Bm, Cm)))
     y = jnp.swapaxes(ys, 0, 1).reshape(Bsz, S, -1).astype(x.dtype)
-    return _mamba_out(p, y, z, eps), {"conv": conv.astype(jnp.float32),
-                                      "ssm": ssm}
+    return _mamba_out(p, y, z, eps, precision), {
+        "conv": conv.astype(jnp.float32), "ssm": ssm}

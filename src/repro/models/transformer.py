@@ -25,6 +25,8 @@ from . import attention as A
 from . import moe as M
 from . import recurrent as R
 from ..core.packing import RowBalancedSparse, pad_packed
+from ..core.sparsity import keep_count
+from ..kernels.ops import serve_dense
 from ..sharding import constrain
 from ..configs.base import ArchConfig
 
@@ -50,8 +52,9 @@ class TransformerLM:
         self.masks_padding = plain and kinds <= set(_MASKS_PADDING)
         if self.masks_padding:
             self.prefill = self._prefill_length
-        # every prunable leaf packs: ServeEngine.prepare packs, and each
-        # projection reads its packed leaf through kernels.ops.rb_project
+        # every prunable leaf can pack: ServeEngine.prepare packs all but
+        # those ``dense_served`` picks; a projection reads a packed leaf
+        # through kernels.ops.rb_project and a dense one through a dot
         self.supports_packed_decode = plain and kinds <= set(_PACKABLE)
 
     # ------------------------------------------------------------- defs
@@ -130,7 +133,8 @@ class TransformerLM:
                                     qk_norm=cfg.qk_norm,
                                     rope_theta=cfg.rope_theta,
                                     use_rope=cfg.use_rope,
-                                    head_dim=cfg.head_dim)
+                                    head_dim=cfg.head_dim,
+                                    precision=cfg.precision)
             if mode == "decode":
                 new_cache = A.kv_cache_update(cache, k, v, pos)  # true kv
                 dq = A.dequantize_cache(new_cache, cfg.jdtype)
@@ -158,7 +162,7 @@ class TransformerLM:
                 # hard-mask dummy TP-padding heads → mathematically inert
                 hm = (jnp.arange(h_eff) < cfg.num_heads).astype(o.dtype)
                 o = o * hm[None, None, :, None]
-            return A.out_project(p["attn"], o), new_cache
+            return A.out_project(p["attn"], o, cfg.precision), new_cache
         if kind == "rec":
             if mode == "decode":
                 return R.rglru_step(p["rec"], x, cache)
@@ -170,7 +174,8 @@ class TransformerLM:
                 pass
             return y, new_state
         if kind == "mamba":
-            mk = dict(groups=cfg.mamba_groups, eps=cfg.norm_eps or 1e-6)
+            mk = dict(groups=cfg.mamba_groups, eps=cfg.norm_eps or 1e-6,
+                      precision=cfg.precision)
             if mode == "decode":
                 return R.mamba2_step(p["mamba"], x, cache, **mk)
             y, state = R.mamba2_apply(
@@ -231,7 +236,7 @@ class TransformerLM:
                     activation=cfg.activation,
                     group_size=cfg.moe_group)
             else:
-                y = L.mlp_apply(p["mlp"], h, cfg.activation)
+                y = L.mlp_apply(p["mlp"], h, cfg.activation, cfg.precision)
             x = self._residual(x, y)
         x = constrain(x, "batch", "seq", "embed")
         return x, (new_cache if new_cache else None), aux
@@ -403,6 +408,20 @@ class TransformerLM:
         x, new_cache, _ = self._run_blocks(params, x, positions, "decode",
                                            cache, pos)
         return self._logits(params, x), new_cache
+
+    @staticmethod
+    def dense_served(plan, batch: int, kind: str) -> frozenset:
+        """Paths of the row-balanced leaves of ``plan`` to serve as their
+        pruned dense matrix rather than packed: those whose gather at
+        ``batch`` activation rows on a ``kind`` device would take longer
+        than streaming the dense bytes (``kernels.ops.serve_dense``). Each
+        projection takes either form; a dense one runs at the model's
+        matmul precision."""
+        return frozenset(
+            path for path, s in plan.sites.items()
+            if s.fmt.name == "row_balanced" and serve_dense(
+                s.d_out, s.d_in, keep_count(s.d_in, s.rule.ratio), batch,
+                kind, jnp.dtype(s.dtype).itemsize))
 
     @staticmethod
     def pad_packed_params(packed):

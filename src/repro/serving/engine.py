@@ -11,7 +11,11 @@ per-token host syncs.
 policy's patterns and, for models that decode through packed kernels
 (``supports_packed_decode``, e.g. the LSTM's rb_dual_spmv + lstm_gates
 datapath), packs the surviving weights so serving exercises the BRDS
-accelerator path rather than masked-dense matmuls.
+accelerator path. A model whose projections take either form
+(``dense_served``, the transformer zoo) packs only the leaves whose
+gather at the engine's slot batch beats their dense bytes on the device
+it runs on; the rest serve as masked-dense matmuls at the model's
+precision.
 """
 from __future__ import annotations
 
@@ -22,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..kernels import ops as kops
 from ..obs import trace as obs_trace
 from ..training.train_loop import param_shardings
 from ..sharding import named_sharding
@@ -89,9 +94,16 @@ class ServeEngine:
         calibrate_lstm``; scale-free fallback when None), the model swaps
         to ``with_quant(plan)``, and packing emits RowBalancedSparseQ8 so
         decode runs the int32-accumulate q8 kernels.
+        A model that serves a projection from either form
+        (``dense_served``) keeps the leaves the engine's slot batch would
+        make the gather slower than the dense bytes as their pruned dense
+        matrix (``kernels.ops.serve_dense``, from the leaf's shape, the
+        batch and the device kind); they are never packed.
         Returns (params, report) — report is None when the engine is
-        dense; a packed report carries ``gather_visit_share`` per packed
-        leaf (``SparsityPlan.gather_visit_shares``)."""
+        dense; a packed report carries ``gather_visit_share`` per
+        projection leaf (``SparsityPlan.gather_visit_shares``; 0.0 for a
+        dense-served leaf, whose source chunks no gather visits) and
+        ``dense_served``, the share of kept entries served dense."""
         if self.sparsity is None:
             return params, None
         plan = (self.sparsity.compile(params)
@@ -132,9 +144,18 @@ class ServeEngine:
         if pack is None:
             pack = getattr(self.model, "supports_packed_decode", False)
         if pack:
-            packed, pack_report = plan.pack(pruned, masks)
-            pack_report["gather_visit_share"] = plan.gather_visit_shares(
-                packed)
+            # leaves whose gather at the slot batch would outlast their
+            # dense bytes serve as the pruned matrix, never packed
+            dense = (self.model.dense_served(plan, self.batch,
+                                             kops.device_kind())
+                     if hasattr(self.model, "dense_served") else frozenset())
+            packed, pack_report = plan.pack(pruned, masks, dense=dense)
+            pack_report["gather_visit_share"] = {
+                **plan.gather_visit_shares(packed),
+                **dict.fromkeys(dense, 0.0)}
+            kept = {p: int(jnp.sum(m)) for p, m in masks.items()}
+            pack_report["dense_served"] = (
+                sum(kept[p] for p in dense) / max(sum(kept.values()), 1))
             packed = self._maybe_partition(packed)
             if not self._dist and hasattr(self.model, "pad_packed_params"):
                 # hoist the kernel-block row padding out of the per-token

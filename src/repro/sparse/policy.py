@@ -330,7 +330,7 @@ class SparsityPlan:
 
     # -- pack -----------------------------------------------------------
     def pack(self, params, masks: dict | None = None,
-             abstract: bool = False):
+             abstract: bool = False, dense=()):
         """Replace every matched leaf with its packed-format rep.
 
         masks=None recomputes masks from the rule ratios (correct both for
@@ -339,7 +339,9 @@ class SparsityPlan:
         pattern. abstract=True builds ShapeDtypeStruct stand-ins (dry-run).
         A policy ``quant`` rule quantizes every row-balanced site on the
         way out (integer codes + per-row scales; the byte accounting
-        reflects the narrowed values). Returns (packed_params, report)."""
+        reflects the narrowed values). Paths in ``dense`` are served as the
+        leaf they are (the pruned dense matrix): no packed copy is built,
+        and their bytes count dense. Returns (packed_params, report)."""
         qscheme = None
         if self.quant is not None:
             from ..quant import (abstract_quantize_packed, packed_bytes_q,
@@ -351,7 +353,7 @@ class SparsityPlan:
         dense_bytes = packed_bytes = 0
         for path, leaf in flat:
             ps = _path_str(path)
-            site = self.sites.get(ps)
+            site = None if ps in dense else self.sites.get(ps)
             if site is None:
                 out_leaves.append(leaf)
                 if hasattr(leaf, "dtype"):

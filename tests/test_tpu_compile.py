@@ -230,3 +230,58 @@ def test_mamba2_ssm_step_compiles_for_v5e(one_chip, no_compile_cache):
     hlo = jax.jit(fn, donate_argnums=0).lower(*structs).compile().as_text()
     ops = _custom_call_names(hlo)
     assert any(re.fullmatch(r"mamba2_ssm_step(\.\d+)?", op) for op in ops), ops
+
+
+def test_dense_served_hybrid_decode_chunk_compiles_for_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """The granite_h_decode cell's decode chunk at its 32 slots and
+    published widths, each projection served as the rule picks on the
+    chip (all dense): it compiles, holds no gather (``rb_spmv``) and keeps
+    the Pallas state step of each of the period's nine Mamba layers."""
+    import json
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from bench.lib import hybrid_lm
+    from repro.kernels import ops
+    from repro.models import build_model
+    from repro.serving import runtime
+    from repro.serving.sampling import SamplingConfig
+    from repro.sparse import transformer_policy
+
+    cfg = json.loads((root / "bench" / "configs"
+                      / "granite_4_h_micro.json").read_text())
+    model = build_model(hybrid_lm.model_config(cfg))
+    abstract = model.abstract_params()
+    plan = transformer_policy(0.75, 0.5).compile(abstract)
+    dense = model.dense_served(plan, 32, "TPU v5 lite")
+    assert dense == frozenset(plan.sites)
+    served, _ = plan.pack(abstract, abstract=True, dense=dense)
+    # the model's own kernels, compiled, where the CPU would take twins
+    monkeypatch.setattr(ops, "model_backend", lambda: None)
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    B, max_len = 32, 4096
+
+    def chunk(params, cache, logits, pos, rng, done, budget):
+        return runtime.decode_loop(model, params, cache, logits, pos, rng,
+                                   1, SamplingConfig(), done=done,
+                                   budget=budget, limit=max_len)
+
+    on_chip = lambda t: jax.tree.map(                      # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        t)
+    args = on_chip((
+        served, jax.eval_shape(lambda: model.init_cache(B, max_len)),
+        jax.ShapeDtypeStruct((B, 1, model.vocab_padded), jnp.float32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.eval_shape(lambda: jax.random.key(0)),
+        jax.ShapeDtypeStruct((B,), jnp.bool_),
+        jax.ShapeDtypeStruct((B,), jnp.int32)))
+    hlo = jax.jit(chunk, donate_argnums=(1,)).lower(
+        *args).compile().as_text()
+    ops_ = _custom_call_names(hlo)
+    assert not any(op.startswith("rb_spmv") for op in ops_), ops_
+    assert any(re.fullmatch(r"mamba2_ssm_step(\.\d+)?", op)
+               for op in ops_), ops_
